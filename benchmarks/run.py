@@ -439,9 +439,6 @@ def _spmd_parity_inner(n: int, t: int, d: int, reps: int):
         kw = {}
         if backend == "gossip":
             kw = dict(backend="gossip", mesh=mesh, axis="node")
-            sh = NamedSharding(mesh, P("node"))
-            params = jax.device_put(params, sh)
-            opt = jax.device_put(opt, sh)
 
         def train_step(p, o, b, s):
             g = p["w"] * 1e-3 + 0.0 * b.mean()
@@ -452,6 +449,10 @@ def _spmd_parity_inner(n: int, t: int, d: int, reps: int):
             return 1.0 - 0.0 * jnp.sum(p["w"])
 
         eng = SwarmEngine(cfg, train_step, eval_fn, data_sizes=sizes, **kw)
+        if backend == "gossip":
+            sh = NamedSharding(eng.mesh, P("node"))
+            params = jax.device_put(params, sh)
+            opt = jax.device_put(opt, sh)
         state = {"p": params, "o": opt}
 
         def once():
@@ -473,26 +474,35 @@ def _spmd_parity_inner(n: int, t: int, d: int, reps: int):
     print(f"spmd_parity_collective_bytes_per_sync,0,{bytes_sync:.0f}")
 
 
+def _cpu_child(mode: str, args, n_devices: int) -> str:
+    """Run ``--inner <mode>`` of this script in a child pinned to the CPU
+    (``JAX_PLATFORMS=cpu``) with ``n_devices`` forced host devices: a
+    rehearsal mesh. The parent may hold the accelerator, and a chip belongs
+    to one process, so a child never asks for it. Returns the child's
+    stdout, whose first row names the platform it ran on."""
+    import subprocess
+    import sys
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
+    env["XLA_FLAGS"] = (
+        env.get("XLA_FLAGS", "")
+        + f" --xla_force_host_platform_device_count={n_devices}").strip()
+    src = os.path.join(os.path.dirname(os.path.abspath(__file__)), "..", "src")
+    env["PYTHONPATH"] = src + os.pathsep + env.get("PYTHONPATH", "")
+    out = subprocess.run(
+        [sys.executable, os.path.abspath(__file__), "--inner", mode,
+         ",".join(map(str, args))],
+        capture_output=True, text=True, env=env, timeout=600)
+    if out.returncode != 0:
+        raise RuntimeError(f"{mode} subprocess failed: {out.stderr[-800:]}")
+    return out.stdout
+
+
 def spmd_parity(smoke: bool = False):
     """ROADMAP SPMD engine parity: a full SwarmEngine(backend="gossip") round
     vs the host backend on a multi-device CPU mesh. Runs in a subprocess so
     the forced host device count doesn't leak into other benchmarks."""
-    import subprocess
-    import sys
     n, t, d, reps = (4, 2, 1 << 12, 3) if smoke else (4, 4, 1 << 16, 10)
-    env = dict(os.environ)
-    env["XLA_FLAGS"] = (env.get("XLA_FLAGS", "")
-                        + f" --xla_force_host_platform_device_count={n}").strip()
-    src = os.path.join(os.path.dirname(os.path.abspath(__file__)), "..", "src")
-    env["PYTHONPATH"] = src + os.pathsep + env.get("PYTHONPATH", "")
-    out = subprocess.run(
-        [sys.executable, os.path.abspath(__file__),
-         "--inner-spmd-parity", f"{n},{t},{d},{reps}"],
-        capture_output=True, text=True, env=env, timeout=600)
-    if out.returncode != 0:
-        raise RuntimeError(f"spmd parity subprocess failed: "
-                           f"{out.stderr[-800:]}")
-    print(out.stdout, end="")
+    print(_cpu_child("spmd-parity", (n, t, d, reps), n), end="")
 
 
 def spmd_parity_smoke():
@@ -612,24 +622,11 @@ def _ring_sync_parity_inner(n: int, d: int, reps: int):
 def ring_sync_parity(smoke: bool = False):
     """Forced-CPU-mesh ring-ppermute parity (subprocess, like spmd_parity):
     keeps the ring-native schedule honest on dev boxes without a mesh."""
-    import subprocess
-    import sys
     n, d, reps = (4, 1 << 12, 3) if smoke else (4, 1 << 16, 10)
-    env = dict(os.environ)
-    env["XLA_FLAGS"] = (env.get("XLA_FLAGS", "")
-                        + f" --xla_force_host_platform_device_count={n}").strip()
-    src = os.path.join(os.path.dirname(os.path.abspath(__file__)), "..", "src")
-    env["PYTHONPATH"] = src + os.pathsep + env.get("PYTHONPATH", "")
-    out = subprocess.run(
-        [sys.executable, os.path.abspath(__file__),
-         "--inner-ring-sync", f"{n},{d},{reps}"],
-        capture_output=True, text=True, env=env, timeout=600)
-    if out.returncode != 0:
-        raise RuntimeError(f"ring sync parity subprocess failed: "
-                           f"{out.stderr[-800:]}")
-    print(out.stdout, end="")
+    out = _cpu_child("ring-sync", (n, d, reps), n)
+    print(out, end="")
     rows = [dict(zip(("name", "us", "derived"), line.split(",", 2)))
-            for line in out.stdout.strip().splitlines() if "," in line]
+            for line in out.strip().splitlines() if "," in line]
     _bench_json_update("ring_parity_smoke" if smoke else "ring_parity", rows,
                        smoke=smoke)
 
@@ -696,24 +693,11 @@ def mesh_wire(smoke: bool = False):
     subprocess measuring the q8 schedules' parity + collective bytes; rows
     land in BENCH_swarm_sync.json (committed on full runs, scratch on
     --smoke)."""
-    import subprocess
-    import sys
     n, d, reps = (4, 1 << 12, 3) if smoke else (4, 1 << 16, 10)
-    env = dict(os.environ)
-    env["XLA_FLAGS"] = (env.get("XLA_FLAGS", "")
-                        + f" --xla_force_host_platform_device_count={n}").strip()
-    src = os.path.join(os.path.dirname(os.path.abspath(__file__)), "..", "src")
-    env["PYTHONPATH"] = src + os.pathsep + env.get("PYTHONPATH", "")
-    out = subprocess.run(
-        [sys.executable, os.path.abspath(__file__),
-         "--inner-mesh-wire", f"{n},{d},{reps}"],
-        capture_output=True, text=True, env=env, timeout=600)
-    if out.returncode != 0:
-        raise RuntimeError(f"mesh wire subprocess failed: "
-                           f"{out.stderr[-800:]}")
-    print(out.stdout, end="")
+    out = _cpu_child("mesh-wire", (n, d, reps), n)
+    print(out, end="")
     rows = [dict(zip(("name", "us", "derived"), line.split(",", 2)))
-            for line in out.stdout.strip().splitlines() if "," in line]
+            for line in out.strip().splitlines() if "," in line]
     _bench_json_update("mesh_wire_smoke" if smoke else "mesh_wire", rows,
                        smoke=smoke)
 
@@ -798,24 +782,9 @@ def hier_sync(smoke: bool = False):
     the flat ring q8 per link class; rows (intra- vs cross-pod bytes,
     predicted and HLO-measured) land in BENCH_swarm_sync.json (committed on
     full runs, scratch on --smoke)."""
-    import subprocess
-    import sys
     k, m, d, reps = (2, 2, 1 << 12, 3) if smoke else (2, 2, 1 << 16, 10)
-    env = dict(os.environ)
-    env["XLA_FLAGS"] = (
-        env.get("XLA_FLAGS", "")
-        + f" --xla_force_host_platform_device_count={k * m}").strip()
-    src = os.path.join(os.path.dirname(os.path.abspath(__file__)), "..", "src")
-    env["PYTHONPATH"] = src + os.pathsep + env.get("PYTHONPATH", "")
-    out = subprocess.run(
-        [sys.executable, os.path.abspath(__file__),
-         "--inner-hier-sync", f"{k},{m},{d},{reps}"],
-        capture_output=True, text=True, env=env, timeout=600)
-    if out.returncode != 0:
-        raise RuntimeError(f"hier sync subprocess failed: "
-                           f"{out.stderr[-800:]}")
     rows = []
-    for line in out.stdout.splitlines():
+    for line in _cpu_child("hier-sync", (k, m, d, reps), k * m).splitlines():
         if line.startswith("hier_sync_rows_json,"):
             rows = json.loads(line.split(",", 2)[2])
         elif line:
@@ -1055,24 +1024,8 @@ def fault_matrix(smoke: bool = False):
     merges = ("fedavg",) if smoke else ("fedavg", "fisher")
     rows = _fault_matrix_cells(merges, kinds, rounds, d)
     if not smoke:
-        import subprocess
-        import sys
         n = 4
-        env = dict(os.environ)
-        env["XLA_FLAGS"] = (
-            env.get("XLA_FLAGS", "")
-            + f" --xla_force_host_platform_device_count={n}").strip()
-        src = os.path.join(os.path.dirname(os.path.abspath(__file__)), "..",
-                           "src")
-        env["PYTHONPATH"] = src + os.pathsep + env.get("PYTHONPATH", "")
-        out = subprocess.run(
-            [sys.executable, os.path.abspath(__file__),
-             "--inner-fault-gossip", f"{n},{d},{rounds}"],
-            capture_output=True, text=True, env=env, timeout=600)
-        if out.returncode != 0:
-            raise RuntimeError(f"fault matrix gossip subprocess failed: "
-                               f"{out.stderr[-800:]}")
-        for line in out.stdout.splitlines():
+        for line in _cpu_child("fault-gossip", (n, d, rounds), n).splitlines():
             if line.startswith("fault_rows_json,"):
                 rows += json.loads(line.split(",", 2)[2])
             elif line:
@@ -1166,62 +1119,44 @@ def roofline_table():
               f"useful={r['useful_ratio']:.3f};peakGiB={r['peak_gib']:.1f}")
 
 
-def main(argv=None) -> None:
+# the --inner modes: each runs inside a CPU child (see _cpu_child)
+INNER = {"spmd-parity": _spmd_parity_inner,
+         "ring-sync": _ring_sync_parity_inner,
+         "mesh-wire": _mesh_wire_inner,
+         "hier-sync": _hier_sync_inner,
+         "fault-gossip": _fault_matrix_gossip_inner}
+
+
+def main(argv=None) -> int:
     import argparse
 
     ap = argparse.ArgumentParser(description="benchmark harness")
     ap.add_argument("--smoke", action="store_true",
                     help="seconds-scale subset for CI (no cached protocols)")
-    ap.add_argument("--inner-spmd-parity", default="",
-                    help="internal: n,t,d,reps (run inside the forced-device"
-                         " subprocess)")
-    ap.add_argument("--inner-ring-sync", default="",
-                    help="internal: n,d,reps (run inside the forced-device"
-                         " subprocess)")
-    ap.add_argument("--inner-mesh-wire", default="",
-                    help="internal: n,d,reps (run inside the forced-device"
-                         " subprocess)")
-    ap.add_argument("--inner-hier-sync", default="",
-                    help="internal: k,m,d,reps (run inside the forced-device"
-                         " subprocess)")
-    ap.add_argument("--inner-fault-gossip", default="",
-                    help="internal: n,d,rounds (run inside the forced-device"
-                         " subprocess)")
+    ap.add_argument("--inner", nargs=2, metavar=("MODE", "ARGS"),
+                    help="internal: run one forced-device mode "
+                         f"({', '.join(INNER)}) with comma-separated ints")
     args = ap.parse_args(argv)
 
-    if args.inner_spmd_parity:
-        n, t, d, reps = map(int, args.inner_spmd_parity.split(","))
-        _spmd_parity_inner(n, t, d, reps)
-        return
-
-    if args.inner_ring_sync:
-        n, d, reps = map(int, args.inner_ring_sync.split(","))
-        _ring_sync_parity_inner(n, d, reps)
-        return
-
-    if args.inner_mesh_wire:
-        n, d, reps = map(int, args.inner_mesh_wire.split(","))
-        _mesh_wire_inner(n, d, reps)
-        return
-
-    if args.inner_hier_sync:
-        k, m, d, reps = map(int, args.inner_hier_sync.split(","))
-        _hier_sync_inner(k, m, d, reps)
-        return
-
-    if args.inner_fault_gossip:
-        n, d, rounds = map(int, args.inner_fault_gossip.split(","))
-        _fault_matrix_gossip_inner(n, d, rounds)
-        return
+    if args.inner:
+        mode, ints = args.inner
+        dev = jax.devices()[0]
+        print(f"{mode.replace('-', '_')}_device,0,platform={dev.platform};"
+              f"kind={dev.device_kind};count={jax.device_count()}")
+        INNER[mode](*map(int, ints.split(",")))
+        return 0
 
     print("name,us_per_call,derived")
     fns = SMOKE if args.smoke else ALL + [roofline_table]
+    failed = []
     for fn in fns:
         try:
             fn()
-        except Exception as e:  # noqa: BLE001
+        except Exception as e:  # noqa: BLE001 — reported, then exit 1
             print(f"{fn.__name__},0,ERROR:{e!r}")
+            failed.append(fn.__name__)
+    return 1 if failed else 0
 
 
 if __name__ == "__main__":
-    main()
+    raise SystemExit(main())

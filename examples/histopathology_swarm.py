@@ -14,6 +14,7 @@ import os
 
 from repro.experiments.histo import (HistoExperimentConfig, run_experiment,
                                      summarize)
+from repro.launch.compile_cache import use_compile_cache
 
 OUT = "experiments/histo"
 
@@ -25,6 +26,7 @@ def main():
     ap.add_argument("--seeds", type=int, default=1,
                     help="paper repeats 5 seeds; default 1 for CPU speed")
     args = ap.parse_args()
+    use_compile_cache()
     os.makedirs(OUT, exist_ok=True)
 
     scenarios = {

@@ -351,6 +351,9 @@ class SwarmEngine:
             raise ValueError("gossip backend needs mesh and axis")
         self.cfg = cfg
         self.backend = backend
+        if backend == "gossip":
+            from repro.core import gossip
+            mesh = gossip.auto_mesh(mesh)
         self.mesh, self.axis, self.param_specs = mesh, axis, param_specs
         self.block = block
         self.interpret = default_interpret() if interpret is None else interpret
@@ -434,6 +437,16 @@ class SwarmEngine:
             self._veval = zoo_veval(_fn_list(eval_fn, "eval_fn"))
         else:
             self._veval = None if eval_fn is None else jax.vmap(eval_fn)
+        from repro.core import gossip
+        if backend == "gossip" and gossip.spans_mesh(mesh, axis):
+            # each device steps and scores its own sites (gossip.per_shard);
+            # with data/model axes inside a site (param_specs), the
+            # partitioner lays the step out from the sync's shardings
+            if self._vstep is not None:
+                self._vstep = gossip.per_shard(self._vstep, mesh, axis,
+                                               replicated=(3,))
+            if self._veval is not None:
+                self._veval = gossip.per_shard(self._veval, mesh, axis)
         self._base_W = mixing_matrix(cfg, self.data_sizes)
         self.spectral_gap = topo.spectral_gap(self._base_W)
 

@@ -65,24 +65,61 @@ from jax.sharding import PartitionSpec as P
 
 from repro.core import comms
 
-try:  # jax>=0.6
-    from jax import shard_map as _shard_map
-except ImportError:  # pragma: no cover
-    from jax.experimental.shard_map import shard_map as _shard_map
+def shard_map(f, mesh, in_specs, out_specs, check_vma=True):
+    """``jax.shard_map`` over the Auto-typed view of ``mesh`` (see
+    :func:`auto_mesh`). check_vma=False: the q8 schedules return replicated
+    state (the reconstruction table / consensus accumulator) that IS
+    identical on every device — each applies the same all_gathered deltas —
+    but the static replication checker can't see through the axis_index
+    arithmetic."""
+    return jax.shard_map(f, mesh=auto_mesh(mesh), in_specs=in_specs,
+                         out_specs=out_specs, check_vma=check_vma)
 
 
-def shard_map(f, mesh, in_specs, out_specs, check_rep=True):
-    # check_rep=False: the q8 schedules return replicated state (the
-    # reconstruction table / consensus accumulator) that IS identical on
-    # every device — each applies the same all_gathered deltas — but the
-    # static replication checker can't see through the axis_index arithmetic
-    kw = dict(mesh=mesh, in_specs=in_specs, out_specs=out_specs)
-    if check_rep is True:
-        return _shard_map(f, **kw)
-    try:
-        return _shard_map(f, check_rep=False, **kw)
-    except TypeError:  # pragma: no cover — kwarg renamed in newer jax
-        return _shard_map(f, check_vma=False, **kw)
+def auto_mesh(mesh):
+    """``mesh`` with every axis of type Auto.
+
+    The swarm round leaves the layout of everything between its collectives
+    to the compiler's sharding propagation. On a mesh with Explicit axes
+    (what ``jax.make_mesh`` builds by default) the node sharding of every
+    shard_map output becomes part of its type, and the vmapped train and
+    eval steps then refuse inputs that mix node-sharded and unplaced
+    arrays."""
+    from jax.sharding import AxisType, Mesh
+    if all(t == AxisType.Auto for t in mesh.axis_types):
+        return mesh
+    return Mesh(mesh.devices, mesh.axis_names,
+                axis_types=(AxisType.Auto,) * len(mesh.axis_names))
+
+
+def spans_mesh(mesh, axis) -> bool:
+    """Whether the swarm ``axis`` (a name or a tuple of names) covers every
+    axis of ``mesh``: each device then holds whole sites, with no data or
+    model axis splitting a site across devices."""
+    names = axis if isinstance(axis, tuple) else (axis,)
+    return set(mesh.axis_names) <= set(names)
+
+
+def per_shard(fn, mesh, axis, replicated=()):
+    """``fn`` (a vmapped per-node step or eval) run shard-locally: every
+    device applies it to its own rows of the node axis, so a site's step
+    runs whole on the device that holds the site, with no collective inside
+    it. Arguments at the positions in ``replicated`` (e.g. the step counter)
+    are whole on every device. Only for a swarm axis that spans the mesh
+    (:func:`spans_mesh`): with data or model axes inside a site, the step
+    is left to the partitioner.
+
+    Left to the partitioner on such a mesh, too, a vmapped 1x1 convolution
+    over a node-sharded batch computes wrong values on the CPU backend of
+    JAX 0.9.0 (the histo CNN's transition layers), where the tests and
+    rehearsals run; the TPU backend computes it correctly."""
+    def run(*args):
+        specs = tuple(P() if i in replicated else P(axis)
+                      for i in range(len(args)))
+        return shard_map(fn, mesh, in_specs=specs, out_specs=P(axis),
+                         check_vma=False)(*args)
+
+    return run
 
 
 def axis_size(mesh, axis) -> int:
@@ -480,6 +517,20 @@ def init_mesh_wire(schedule: str, payload, *, n_shards: int,
     raise ValueError(f"no mesh wire state for schedule {schedule!r}")
 
 
+def mesh_wire_shardings(wire, mesh, axis):
+    """Where each leaf of an :func:`init_mesh_wire` pytree lives, as the q8
+    shard_maps read and write it: the gathered reconstruction ``table`` and
+    the psum consensus row ``cons`` are replicated on every device; every
+    other leaf is one row (or chunk) per shard along the swarm axis."""
+    from jax.sharding import NamedSharding
+
+    def placed(key, sub):
+        spec = P() if key in ("table", "cons") else P(axis)
+        return jax.tree.map(lambda _: NamedSharding(mesh, spec), sub)
+
+    return {k: placed(k, v) for k, v in wire.items()}
+
+
 def reset_mesh_wire(wire):
     """Quarantine the WHOLE mesh EF wire state (crash→rejoin recovery).
 
@@ -627,7 +678,7 @@ def matrix_gossip_q8(stacked, W, wire, mesh, axis: str, inner_specs=None,
         in_spec = P(axis, *inner)
         tab_spec = P(None, *inner)
         sm = shard_map(f, mesh, in_specs=(in_spec, tab_spec, P()),
-                       out_specs=(in_spec, tab_spec), check_rep=False)
+                       out_specs=(in_spec, tab_spec), check_vma=False)
         return sm(x, table, Wj)
 
     specs = _inner_spec_tree(stacked, inner_specs)
@@ -679,7 +730,7 @@ def topo_fisher_gossip_q8(stacked, fishers, rows, wire, mesh, axis: str,
         sm = shard_map(f, mesh,
                        in_specs=(in_spec, in_spec, tab_spec, tab_spec, P()),
                        out_specs=(in_spec, tab_spec, tab_spec),
-                       check_rep=False)
+                       check_vma=False)
         return sm(x, fsh, tn, tm, Wj)
 
     specs = _inner_spec_tree(stacked, inner_specs)
@@ -755,7 +806,7 @@ def fedavg_psum_q8(stacked, weights, wire, mesh, axis: str, inner_specs=None,
         sm = shard_map(f, mesh,
                        in_specs=(in_spec, in_spec, P(), in_spec, P()),
                        out_specs=(in_spec, in_spec, P(), in_spec),
-                       check_rep=False)
+                       check_vma=False)
         return sm(x, ref, cons, cres, w)
 
     specs = _inner_spec_tree(stacked, inner_specs)
@@ -801,7 +852,7 @@ def fisher_psum_q8(stacked, fishers, wire, mesh, axis: str, inner_specs=None,
                       in_spec, in_spec),
             out_specs=(in_spec, in_spec, in_spec, P(), P(), in_spec,
                        in_spec),
-            check_rep=False)
+            check_vma=False)
         return sm(x, fsh, rn, rm, cn, cm, qn_res, qm_res)
 
     specs = _inner_spec_tree(stacked, inner_specs)
@@ -910,7 +961,7 @@ def hier_fedavg_ring_q8(stacked, weights, pod_rows, wire, mesh, axis,
     def leaf(x, ref, lft, rgt, spec):
         in_spec = P(axis)
         sm = shard_map(f, mesh, in_specs=(in_spec,) * 4 + (P(), P()),
-                       out_specs=(in_spec,) * n_out, check_rep=False)
+                       out_specs=(in_spec,) * n_out, check_vma=False)
         return sm(x, ref, lft, rgt, w, Wp)
 
     specs = _inner_spec_tree(stacked, inner_specs)
@@ -991,7 +1042,7 @@ def hier_fisher_ring_q8(stacked, fishers, pod_rows, wire, mesh, axis,
     def leaf(x, fsh, rn, rm, ln, lm, rgn, rgm, spec):
         in_spec = P(axis)
         sm = shard_map(f, mesh, in_specs=(in_spec,) * 8 + (P(),),
-                       out_specs=(in_spec,) * n_out, check_rep=False)
+                       out_specs=(in_spec,) * n_out, check_vma=False)
         return sm(x, fsh, rn, rm, ln, lm, rgn, rgm, Wp)
 
     specs = _inner_spec_tree(stacked, inner_specs)

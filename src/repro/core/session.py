@@ -44,10 +44,11 @@ from typing import Any, Callable, Optional, Sequence
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.checkpointing import load_metadata, load_pytree, save_pytree
 from repro.configs.base import SwarmConfig
-from repro.core import comms
+from repro.core import comms, gossip
 from repro.core import merge_impl as merge_lib
 from repro.core.engine import SwarmEngine
 from repro.kernels.fused_merge import DEFAULT_BLOCK
@@ -213,11 +214,14 @@ class SwarmSession:
         # schedule-specific sharded mesh EF pytree; bf16-on-mesh is a
         # stateless cast (no state)
         wire = self.engine._auto_wire(stacked_params, None)
-        self._state = SwarmState(
+        self.mesh, self.axis = self.engine.mesh, axis
+        self._placed = backend == "gossip" and gossip.spans_mesh(self.mesh,
+                                                                 axis)
+        self._state = self._place_state(SwarmState(
             params=stacked_params, opt_state=stacked_opt,
             stats=self.engine.init_stats(stacked_params), wire=wire,
             active=jnp.ones((n,), bool), rng=rng,
-            round=jnp.asarray(0, jnp.int32), step=jnp.asarray(0, jnp.int32))
+            round=jnp.asarray(0, jnp.int32), step=jnp.asarray(0, jnp.int32)))
         # cost-model-driven schedule choice, surfaced for logs/benchmarks;
         # predicted_link_bytes splits the prediction per link class on a
         # two-level ("pod", "node") mesh ({"intra": ..., "cross": ...})
@@ -235,6 +239,52 @@ class SwarmSession:
         self._round_jit = jax.jit(self._round_impl, donate_argnums=(0,))
         self._rounds_jit = jax.jit(self._rounds_impl, donate_argnums=(0,))
         self._local_jit = jax.jit(self._local_impl, donate_argnums=(0,))
+
+    # -- mesh placement (gossip backend) -------------------------------------
+    # On a mesh the swarm axis spans (one or more whole sites per device),
+    # each site's params, optimizer state, stats, wire rows and data live on
+    # the device that owns the site. The state, each round's batches and
+    # ``val`` are placed there once, exactly as the compiled round returns
+    # them, so the second round reuses the first one's program. With data or
+    # model axes inside a site (param_specs), and on the other backends,
+    # placement is left to jit.
+
+    def _on_mesh(self, tree, lead: int = 0):
+        """Place ``tree`` with dim ``lead`` of every leaf (its node axis)
+        sharded over the swarm axis. Identity unless ``self._placed``."""
+        if not self._placed or tree is None:
+            return tree
+
+        def put(x):   # host arrays go straight to their shards
+            sharded = jnp.ndim(x) > lead
+            spec = P(*(None,) * lead, self.axis) if sharded else P()
+            return jax.device_put(x, NamedSharding(self.mesh, spec))
+
+        return jax.tree.map(put, tree)
+
+    def _place_state(self, state: SwarmState) -> SwarmState:
+        if not self._placed:
+            return state
+        wire = state.wire
+        if wire is not None:
+            wire = jax.device_put(
+                wire, gossip.mesh_wire_shardings(wire, self.mesh, self.axis))
+        return dataclasses.replace(
+            state, params=self._on_mesh(state.params),
+            opt_state=self._on_mesh(state.opt_state),
+            stats=self._on_mesh(state.stats), wire=wire,
+            active=self._replicated(state.active),
+            rng=self._replicated(state.rng),
+            round=self._replicated(state.round),
+            step=self._replicated(state.step))
+
+    def _replicated(self, x):
+        """Whole on every device: the counters, the rng and the membership
+        mask (it feeds the mixing-matrix build, whose [N, N] rows and
+        columns both index nodes)."""
+        if not self._placed:
+            return x
+        return jax.device_put(x, NamedSharding(self.mesh, P()))
 
     # -- state ---------------------------------------------------------------
 
@@ -262,7 +312,7 @@ class SwarmSession:
     def load_state(self, state: SwarmState) -> None:
         """Replace the session's state (all backends)."""
         if self.backend != "host":
-            self._state = state
+            self._state = self._place_state(state)
             return
         lr = self._learner
         n = self.cfg.n_nodes
@@ -313,14 +363,16 @@ class SwarmSession:
                 self._learner.nodes[i].active = bool(v)
             return
         self._state = dataclasses.replace(
-            self._state, active=jnp.asarray(mask).astype(bool))
+            self._state,
+            active=self._replicated(jnp.asarray(mask).astype(bool)))
 
     def _set_active_index(self, node: int, value: bool) -> None:
         if self.backend == "host":
             self._learner.nodes[node].active = value
             return
         self._state = dataclasses.replace(
-            self._state, active=self._state.active.at[node].set(value))
+            self._state,
+            active=self._replicated(self._state.active.at[node].set(value)))
 
     def quarantine_wire(self, node: Optional[int] = None) -> None:
         """Reset the error-feedback wire state for a crash→rejoin.
@@ -347,7 +399,6 @@ class SwarmSession:
                 lambda x: None if x is None else x.at[node].set(0),
                 wire, is_leaf=lambda v: v is None)
         else:
-            from repro.core import gossip
             new_wire = gossip.reset_mesh_wire(wire)
         self._state = dataclasses.replace(self._state, wire=new_wire)
 
@@ -419,7 +470,8 @@ class SwarmSession:
                     "in-graph fault injection (faults=) needs a compiled "
                     "backend; lower corrupt events to drops on the host loop")
             return self._host_round(batches, val)
-        self._state, out = self._round_jit(self._state, batches, val, faults)
+        self._state, out = self._round_jit(
+            self._state, self._on_mesh(batches, 1), self._on_mesh(val), faults)
         return out
 
     def run_rounds(self, batches, val):
@@ -430,7 +482,8 @@ class SwarmSession:
         if self.backend == "host":
             logs = [self._host_round(rb, val) for rb in batches]
             return {k: [lg[k] for lg in logs] for k in logs[0]}
-        self._state, tm, logs = self._rounds_jit(self._state, batches, val)
+        self._state, tm, logs = self._rounds_jit(
+            self._state, self._on_mesh(batches, 2), self._on_mesh(val))
         return dict(logs, train=tm)
 
     def run_local(self, batches):
@@ -439,7 +492,8 @@ class SwarmSession:
             for step_batches in batches:
                 self._learner.local_steps(step_batches)
             return None
-        self._state, tm = self._local_jit(self._state, batches)
+        self._state, tm = self._local_jit(self._state,
+                                          self._on_mesh(batches, 1))
         return tm
 
     def _host_round(self, batches, val):

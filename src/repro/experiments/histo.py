@@ -130,34 +130,18 @@ def _stack_vals(vals):
     vm = np.zeros((n, vmax), bool)
     for i, (x, y) in enumerate(vals):
         vx[i, :len(y)], vy[i, :len(y)], vm[i, :len(y)] = x, y, True
-    return jnp.asarray(vx), jnp.asarray(vy), jnp.asarray(vm)
+    return vx, vy, vm
 
 
-def _train_loop(ecfg, train_step, shards, *, swarm_cfg=None, log=None):
-    """Train nodes (swarm if swarm_cfg else isolated). Returns node params.
-
-    Runs on `SwarmSession` (engine backend): the whole sync round —
-    `sync_every` vmapped local steps, the in-graph gate metric selected by
-    ``swarm.gate_metric`` (sort-based AUC by default), fused Pallas commit —
-    is one compiled program; `run_rounds` scans over rounds with zero host
-    round-trips. The swarm config's merge method (including fisher/gradmatch
-    with in-graph importance accumulation) and `overlap_sync` double-buffered
-    rounds are handled entirely inside the session's compiled drivers.
-    """
-    key = jax.random.key(ecfg.seed + 42)   # shared init = warm-start effect
-    n = len(shards)
-
-    vals, trains = [], []
-    for x, y in shards:
-        n_val = max(8, int(len(y) * ecfg.val_frac))
-        vals.append((x[:n_val], y[:n_val]))
-        trains.append((x[n_val:], y[n_val:]))
-
-    params = _init_params(ecfg, key)
-    xs, ys = _batch_stream(ecfg, trains)
-    val = _stack_vals(vals)
-
-    cfg = swarm_cfg or SwarmConfig(n_nodes=n, sync_every=10**9,
+def _swarm_session(ecfg, train_step, shards, swarm_cfg=None, **session_kw):
+    """The `SwarmSession` that `_train_loop` trains: every site starts from
+    the same init (warm-start effect) with fresh AdamW state, and its gate
+    scores ``cfg.gate_metric`` on the site's validation split. Engine
+    backend unless ``session_kw`` names another, e.g.
+    ``backend="gossip", mesh=mesh, axis="node"`` for one site per device.
+    Without ``swarm_cfg`` the sites never sync (isolated local learners)."""
+    params = _init_params(ecfg, jax.random.key(ecfg.seed + 42))
+    cfg = swarm_cfg or SwarmConfig(n_nodes=len(shards), sync_every=10**9,
                                    gate_metric="auc")
     metric = gate_metric_fn(cfg.gate_metric)
 
@@ -165,26 +149,57 @@ def _train_loop(ecfg, train_step, shards, *, swarm_cfg=None, log=None):
         x, y, m = v
         return metric(jax.nn.sigmoid(forward_cnn(p, x)), y, m)
 
-    sess = SwarmSession(cfg, train_step, eval_fn, params=params,
+    return SwarmSession(cfg, train_step, eval_fn, params=params,
                         opt_state=adamw_init(params), seed=ecfg.seed,
-                        data_sizes=[len(y) for _, y in shards])
+                        data_sizes=[len(y) for _, y in shards], **session_kw)
+
+
+def _train_loop(ecfg, train_step, shards, *, swarm_cfg=None, log=None,
+                session=None):
+    """Train nodes (swarm if swarm_cfg else isolated). Returns node params.
+    ``log``, when given, is a list the sync records are appended to.
+    ``session``, when given, is a `_swarm_session` to train in place of a
+    new engine-backend one (its config then stands for ``swarm_cfg``).
+
+    Runs on `SwarmSession`: the whole sync round —
+    `sync_every` vmapped local steps, the in-graph gate metric selected by
+    ``swarm.gate_metric`` (sort-based AUC by default), fused Pallas commit —
+    is one compiled program; `run_rounds` scans over rounds with zero host
+    round-trips. The swarm config's merge method (including fisher/gradmatch
+    with in-graph importance accumulation) and `overlap_sync` double-buffered
+    rounds are handled entirely inside the session's compiled drivers.
+    """
+    sess = session or _swarm_session(ecfg, train_step, shards, swarm_cfg)
+    cfg = sess.cfg
+
+    vals, trains = [], []
+    for x, y in shards:
+        n_val = max(8, int(len(y) * ecfg.val_frac))
+        vals.append((x[:n_val], y[:n_val]))
+        trains.append((x[n_val:], y[n_val:]))
+
+    xs, ys = _batch_stream(ecfg, trains)
+    val = _stack_vals(vals)
 
     sync_log = []
-    if swarm_cfg is None or cfg.sync_every > ecfg.steps:
-        sess.run_local((jnp.asarray(xs), jnp.asarray(ys)))
+    if cfg.sync_every > ecfg.steps:
+        sess.run_local((xs, ys))
     else:
         t = cfg.sync_every
         rounds = ecfg.steps // t
-        head = (jnp.asarray(xs[:rounds * t]).reshape((rounds, t) + xs.shape[1:]),
-                jnp.asarray(ys[:rounds * t]).reshape((rounds, t) + ys.shape[1:]))
+        # host arrays: the session places them (one site per device on the
+        # gossip backend) without staging the whole stream on one device
+        head = (xs[:rounds * t].reshape((rounds, t) + xs.shape[1:]),
+                ys[:rounds * t].reshape((rounds, t) + ys.shape[1:]))
         logs = sess.run_rounds(head, val)
         if ecfg.steps % t:
-            sess.run_local((jnp.asarray(xs[rounds * t:]),
-                            jnp.asarray(ys[rounds * t:])))
+            sess.run_local((xs[rounds * t:], ys[rounds * t:]))
         gates = np.asarray(logs["gates"])
         ml = np.asarray(logs["metric_local"])
         mm = np.asarray(logs["metric_merged"])
+        loss = np.asarray(logs["train"]["loss"]).mean(axis=(1, 2))
         sync_log = [{"step": (r + 1) * t, "gates": gates[r].tolist(),
+                     "loss": float(loss[r]),
                      "metric_local": ml[r].tolist(),
                      "metric_merged": mm[r].tolist(),
                      "spectral_gap": sess.engine.spectral_gap}

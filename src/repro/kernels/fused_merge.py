@@ -13,11 +13,15 @@ Two entry points:
   * ``fused_merge``      — one node's commit:   [N, D] → [D]
   * ``fused_merge_all``  — the whole swarm's commit in one launch:
                            [N, D] → [N, D] with a full mixing matrix W [N, N]
-                           and per-node gate bits. Grid order is
-                           (d-blocks, nodes) so the [N, BLOCK] input tile is
-                           fetched once per d-block and reused for all N output
-                           rows — (N + N)·BLOCK bytes per column block, still
-                           the roofline minimum.
+                           and per-node gate bits. Each grid step reads one
+                           [N, BLOCK] tile, contracts it against the whole W
+                           on the MXU and writes all N committed rows —
+                           (N + N)·BLOCK bytes per column block, still the
+                           roofline minimum.
+
+Mosaic layout rules shape the operands: W and the ``[N, 1]`` int32 gate
+column are whole-array blocks, tiles are ``[N, BLOCK]`` (BLOCK a multiple of
+128), and scalars live in SMEM.
 
 ``fused_merge_all`` optionally takes per-element importance weights
 ``imp [N, D]`` (diagonal Fisher mass). The merged row then becomes the
@@ -41,6 +45,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 DEFAULT_BLOCK = 16_384  # 4 nodes × 16k × 4B = 256 KiB VMEM working set
 
@@ -66,13 +71,26 @@ def auto_block(n: int, streams: int, *, out_rows: int = 1,
     return min(block, max(align, cap // align * align))
 
 
-def _merge_kernel(x_ref, w_ref, gate_ref, self_idx_ref, o_ref):
-    """x [N, B] tile; w [N]; gate/self_idx scalars (SMEM); o [B] tile."""
+def _mix(w, x):
+    """w [M, N] · x [N, B] on the MXU at full f32 precision."""
+    return jax.lax.dot(w.astype(jnp.float32), x,
+                       precision=jax.lax.Precision.HIGHEST)
+
+
+def _gated(g_ref, merged, local):
+    """Per-row select: accepted rows take ``merged``, rejected rows keep the
+    exact ``local`` values. ``g_ref`` is the [N, 1] int32 gate column."""
+    g = jnp.broadcast_to(g_ref[...], local.shape) != 0
+    return jnp.where(g, merged, local)
+
+
+def _merge_kernel(x_ref, w_ref, idx_ref, gate_ref, o_ref):
+    """x [N, B] tile; w [1, N] mixing row; idx/gate scalars (SMEM); o [1, B]."""
     x = x_ref[...].astype(jnp.float32)              # [N, B]
-    w = w_ref[...].astype(jnp.float32)              # [N]
-    merged = jnp.einsum("n,nb->b", w, x)
-    self_row = jax.lax.dynamic_index_in_dim(x, self_idx_ref[0], axis=0,
-                                            keepdims=False)
+    merged = _mix(w_ref[...], x)                     # [1, B]
+    rows = jax.lax.broadcasted_iota(jnp.int32, x.shape, 0)
+    self_row = jnp.sum(jnp.where(rows == idx_ref[0], x, 0.0), axis=0,
+                       keepdims=True)
     gate = gate_ref[0] != 0
     o_ref[...] = jnp.where(gate, merged, self_row).astype(o_ref.dtype)
 
@@ -91,52 +109,46 @@ def fused_merge(stacked, weights, self_idx, gate, *, block: int = DEFAULT_BLOCK,
     if pad:
         stacked = jnp.pad(stacked, ((0, 0), (0, pad)))
     dp = d + pad
-    grid = (dp // block,)
+    smem = pl.BlockSpec(memory_space=pltpu.SMEM)
 
     out = pl.pallas_call(
         _merge_kernel,
-        grid=grid,
+        grid=(dp // block,),
         in_specs=[
-            pl.BlockSpec((n, block), lambda i: (0, i)),
-            pl.BlockSpec((n,), lambda i: (0,)),
-            pl.BlockSpec(memory_space=pl.ANY),
-            pl.BlockSpec(memory_space=pl.ANY),
+            pl.BlockSpec((n, block), lambda j: (0, j)),
+            pl.BlockSpec((1, n), lambda j: (0, 0)),
+            smem,
+            smem,
         ],
-        out_specs=pl.BlockSpec((block,), lambda i: (i,)),
-        out_shape=jax.ShapeDtypeStruct((dp,), stacked.dtype),
+        out_specs=pl.BlockSpec((1, block), lambda j: (0, j)),
+        out_shape=jax.ShapeDtypeStruct((1, dp), stacked.dtype),
         interpret=interpret,
-    )(stacked, weights.astype(jnp.float32),
-      jnp.asarray(gate, jnp.int32).reshape(1),
-      jnp.asarray(self_idx, jnp.int32).reshape(1))
-    return out[:d]
+    )(stacked, jnp.asarray(weights, jnp.float32).reshape(1, n),
+      jnp.asarray(self_idx, jnp.int32).reshape(1),
+      jnp.asarray(gate, jnp.int32).reshape(1))
+    return out[0, :d]
 
 
 def _merge_all_kernel(x_ref, w_ref, g_ref, o_ref):
-    """x [N, B] tile (all nodes); w [1, N] mixing row of node i; g [1];
-    o [1, B] — node i's committed slice. Grid is (d-blocks, nodes)."""
-    i = pl.program_id(1)
-    x = x_ref[...].astype(jnp.float32)              # [N, B]
-    w = w_ref[...].astype(jnp.float32)[0]           # [N]
-    merged = jnp.einsum("n,nb->b", w, x)
-    self_row = jax.lax.dynamic_index_in_dim(x, i, axis=0, keepdims=False)
-    gate = g_ref[0] != 0
-    o_ref[...] = jnp.where(gate, merged, self_row)[None].astype(o_ref.dtype)
+    """x [N, B] tile (all nodes); w [N, N]; g [N, 1] gate bits; o [N, B]."""
+    x = x_ref[...].astype(jnp.float32)
+    o_ref[...] = _gated(g_ref, _mix(w_ref[...], x), x).astype(o_ref.dtype)
 
 
 def _merge_all_imp_kernel(x_ref, f_ref, w_ref, g_ref, o_ref):
-    """Importance-weighted form: x/f [N, B] tiles; w [1, N] row of node i;
-    g [1]; o [1, B].  merged = Σ_j w_j f_j x_j / Σ_j w_j f_j  per element."""
-    i = pl.program_id(1)
-    x = x_ref[...].astype(jnp.float32)              # [N, B]
-    f = f_ref[...].astype(jnp.float32)              # [N, B]
-    w = w_ref[...].astype(jnp.float32)[0]           # [N]
-    wf = f * w[:, None]
-    num = jnp.einsum("nb,nb->b", wf, x)
-    den = wf.sum(0)
-    merged = num / jnp.maximum(den, 1e-30)
-    self_row = jax.lax.dynamic_index_in_dim(x, i, axis=0, keepdims=False)
-    gate = g_ref[0] != 0
-    o_ref[...] = jnp.where(gate, merged, self_row)[None].astype(o_ref.dtype)
+    """Importance-weighted form: x/f [N, B] tiles; w [N, N]; g [N, 1];
+    o [N, B].  merged = W·(f⊙x) / W·f  per element."""
+    x = x_ref[...].astype(jnp.float32)
+    f = f_ref[...].astype(jnp.float32)
+    w = w_ref[...]
+    merged = _mix(w, f * x) / jnp.maximum(_mix(w, f), 1e-30)
+    o_ref[...] = _gated(g_ref, merged, x).astype(o_ref.dtype)
+
+
+def _gate_column(gates, n: int):
+    """[N] accept bits → the [N, 1] int32 gate operand every commit kernel
+    reads (a whole-array block; the kernel broadcasts it along lanes)."""
+    return jnp.asarray(gates).astype(jnp.int32).reshape(n, 1)
 
 
 @functools.partial(jax.jit, static_argnames=("block", "interpret"))
@@ -144,21 +156,21 @@ def fused_merge_all(stacked, W, gates, imp=None, *, block: int = DEFAULT_BLOCK,
                     interpret: bool = False):
     """stacked [N, D] → committed [N, D]:  out[i] = gate[i] ? Σ_j W[i,j] θ_j : θ_i.
 
-    W: [N, N] row-stochastic mixing matrix; gates: [N] acceptance bits. The
-    node axis is the innermost grid dimension, so each [N, BLOCK] tile is
-    loaded once and serves every node's output row.
+    W: [N, N] row-stochastic mixing matrix; gates: [N] acceptance bits. Each
+    grid step loads one [N, BLOCK] tile, contracts it against the whole W
+    and writes every node's committed row of that column block.
 
     imp: optional [N, D] per-element importance weights — switches to the
     normalized weighted merge  Σ_j W[i,j]·imp[j]⊙θ_j / Σ_j W[i,j]·imp[j]
     (fisher / gradmatch commits), still one pass over the tile.
 
     The tile width is auto-capped so the VMEM working set — one [N, BLOCK]
-    tile per input stream (two with ``imp``) plus the output row — fits
+    tile per input stream (two with ``imp``) plus the output tile — fits
     `VMEM_BUDGET` regardless of swarm size N (see :func:`auto_block`).
     """
     n, d = stacked.shape
-    block = min(auto_block(n, 1 if imp is None else 2, block=block),
-                max(128, d))
+    block = min(auto_block(n, 1 if imp is None else 2, out_rows=n,
+                           block=block), max(128, d))
     pad = (-d) % block
     if pad:
         stacked = jnp.pad(stacked, ((0, 0), (0, pad)))
@@ -166,22 +178,21 @@ def fused_merge_all(stacked, W, gates, imp=None, *, block: int = DEFAULT_BLOCK,
             imp = jnp.pad(imp, ((0, 0), (0, pad)))
     dp = d + pad
 
-    tile_spec = pl.BlockSpec((n, block), lambda j, i: (0, j))
+    tile_spec = pl.BlockSpec((n, block), lambda j: (0, j))
     operands = [stacked]
     in_specs = [tile_spec]
     if imp is not None:  # same tiling, one extra [N, B] importance stream
         operands.append(jnp.asarray(imp, jnp.float32))
         in_specs.append(tile_spec)
-    operands += [jnp.asarray(W, jnp.float32),
-                 jnp.asarray(gates).astype(jnp.int32)]
-    in_specs += [pl.BlockSpec((1, n), lambda j, i: (i, 0)),
-                 pl.BlockSpec((1,), lambda j, i: (i,))]
+    operands += [jnp.asarray(W, jnp.float32), _gate_column(gates, n)]
+    in_specs += [pl.BlockSpec((n, n), lambda j: (0, 0)),
+                 pl.BlockSpec((n, 1), lambda j: (0, 0))]
 
     out = pl.pallas_call(
         _merge_all_kernel if imp is None else _merge_all_imp_kernel,
-        grid=(dp // block, n),
+        grid=(dp // block,),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, block), lambda j, i: (i, j)),
+        out_specs=tile_spec,
         out_shape=jax.ShapeDtypeStruct((n, dp), stacked.dtype),
         interpret=interpret,
     )(*operands)
@@ -206,7 +217,7 @@ def _quant_block(v, wire_dtype: str, wire_block: int):
 def _quant_merge_kernel(x_ref, r_ref, w_ref, g_ref, o_ref, ro_ref, *,
                         wire_dtype, wire_block):
     """x (local params) / r (wire reference θ̂): [N, B] tiles; w: [N, N];
-    g: [N]; outputs: o committed [N, B], ro new reference [N, B].
+    g: [N, 1]; outputs: o committed [N, B], ro new reference [N, B].
 
     One VMEM pass per column block: quantize the EF delta v = x − θ̂ (per-
     wire-block int8 scales or bf16 cast), advance the reference, contract
@@ -216,10 +227,7 @@ def _quant_merge_kernel(x_ref, r_ref, w_ref, g_ref, o_ref, ro_ref, *,
     x = x_ref[...].astype(jnp.float32)
     r = r_ref[...].astype(jnp.float32)
     rp = r + _quant_block(x - r, wire_dtype, wire_block)
-    w = w_ref[...].astype(jnp.float32)                      # [N, N]
-    merged = jax.lax.dot(w, rp, precision=jax.lax.Precision.HIGHEST)
-    g = g_ref[...] != 0                                     # [N]
-    o_ref[...] = jnp.where(g[:, None], merged, x).astype(o_ref.dtype)
+    o_ref[...] = _gated(g_ref, _mix(w_ref[...], rp), x).astype(o_ref.dtype)
     ro_ref[...] = rp
 
 
@@ -231,13 +239,9 @@ def _quant_merge_imp_kernel(x_ref, r_ref, f_ref, w_ref, g_ref, o_ref, ro_ref,
     r = r_ref[...].astype(jnp.float32)
     rp = r + _quant_block(x - r, wire_dtype, wire_block)
     f = f_ref[...].astype(jnp.float32)                      # [N, B]
-    w = w_ref[...].astype(jnp.float32)                      # [N, N]
-    hi = jax.lax.Precision.HIGHEST
-    num = jax.lax.dot(w, f * rp, precision=hi)
-    den = jax.lax.dot(w, f, precision=hi)
-    merged = num / jnp.maximum(den, 1e-30)
-    g = g_ref[...] != 0
-    o_ref[...] = jnp.where(g[:, None], merged, x).astype(o_ref.dtype)
+    w = w_ref[...]
+    merged = _mix(w, f * rp) / jnp.maximum(_mix(w, f), 1e-30)
+    o_ref[...] = _gated(g_ref, merged, x).astype(o_ref.dtype)
     ro_ref[...] = rp
 
 
@@ -283,10 +287,9 @@ def fused_quant_merge_all(stacked, wire_ref, W, gates, imp=None, *,
     if imp is not None:
         operands.append(jnp.asarray(imp, jnp.float32))
         in_specs.append(tile)
-    operands += [jnp.asarray(W, jnp.float32),
-                 jnp.asarray(gates).astype(jnp.int32)]
+    operands += [jnp.asarray(W, jnp.float32), _gate_column(gates, n)]
     in_specs += [pl.BlockSpec((n, n), lambda j: (0, 0)),
-                 pl.BlockSpec((n,), lambda j: (0,))]
+                 pl.BlockSpec((n, 1), lambda j: (0, 0))]
 
     kern = functools.partial(
         _quant_merge_kernel if imp is None else _quant_merge_imp_kernel,

@@ -66,7 +66,7 @@ def lora_matmul(x, w, a, b, scale, *, bm: int = 128, bn: int = 128,
             pl.BlockSpec((bk, bn), lambda i, j, kk: (kk, j)),
             pl.BlockSpec((bk, r), lambda i, j, kk: (kk, 0)),
             pl.BlockSpec((r, bn), lambda i, j, kk: (0, j)),
-            pl.BlockSpec(memory_space=pl.ANY),
+            pl.BlockSpec(memory_space=pltpu.SMEM),
         ],
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, kk: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), x.dtype),

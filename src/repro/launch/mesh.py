@@ -64,10 +64,14 @@ def make_two_level_swarm_mesh(n_pods: int = 2, per_pod: int = 2):
 
 
 def make_swarm_mesh(n_nodes: int = 4, *, multi_pod: bool = False):
-    """Swarm training mesh: leading `node` axis is the gossip axis.
+    """Swarm training mesh: leading `node` axis is the gossip axis, built
+    from the devices present. Returns ``(mesh, axis_name)``.
 
-    single-pod: (node, data, model) = (n, 16//n? , 16) — we factor the data
-    axis of the production mesh into (node, data): same 256 chips.
+    fewer than 256 devices (one host of 1-4 chips, or a forced CPU device
+    count): a 1-D ``("node",)`` mesh over the first ``n_nodes`` devices —
+    one site per device, e.g. four sites on a v5e 2x2 host.
+    256-chip pod: (node, data, model) = (n, 16 // n, 16) — the data axis of
+    the production mesh factored into (node, data).
     multi-pod: gossip over `pod` — (pod, data, model) = (2, 16, 16), i.e. the
     production mesh itself; swarm code treats `pod` as the node axis.
     """
@@ -76,8 +80,16 @@ def make_swarm_mesh(n_nodes: int = 4, *, multi_pod: bool = False):
     if multi_pod:
         mesh = make_production_mesh(multi_pod=True)
         return mesh, "pod"
+    devs = jax.devices()
+    if len(devs) < 256:
+        if len(devs) < n_nodes:
+            raise RuntimeError(
+                f"one site per device needs {n_nodes} devices, have "
+                f"{len(devs)}")
+        return jax.make_mesh((n_nodes,), ("node",),
+                             devices=devs[:n_nodes]), "node"
     if 16 % n_nodes:
         raise ValueError("n_nodes must divide 16 on the single-pod mesh")
     shape = (n_nodes, 16 // n_nodes, 16)
-    devs = jax.devices()[: int(np.prod(shape))]
+    devs = devs[: int(np.prod(shape))]
     return jax.make_mesh(shape, ("node", "data", "model"), devices=devs), "node"

@@ -128,6 +128,7 @@ def main():
     from repro.configs import get_config, smoke_variant
     from repro.core.lora import inject_lora
     from repro.data import make_lm_stream
+    from repro.launch.compile_cache import use_compile_cache
     from repro.models import build_model
     from repro.optim import EarlyStopper
 
@@ -158,6 +159,7 @@ def main():
                          "(session.msgpack written by --ckpt-dir)")
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
+    use_compile_cache()
 
     cfg = get_config(args.arch)
     if args.smoke:

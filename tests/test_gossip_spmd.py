@@ -234,6 +234,48 @@ l2 = jax.tree.leaves(jax.tree.map(lambda a, b: float(jnp.abs(a[2]-b[2]).max()),
 assert max(l2) == 0.0  # absent node keeps its params
 print("OK dynamic_traced")
 
+# --- session on a (node, model) mesh: params sharded inside each site ----
+from jax.sharding import PartitionSpec as P
+from repro.core.session import SwarmSession
+
+def lin_step(p, o, b, s):
+    xx, yy = b
+    l, g = jax.value_and_grad(
+        lambda q: jnp.mean((xx @ q["w"] + q["b"] - yy) ** 2))(p)
+    return jax.tree.map(lambda a, d: a - 0.1 * d, p, g), o, {"loss": l}
+
+def lin_eval(p, v):
+    xx, yy = v
+    return 1.0 / (1.0 + jnp.mean((xx @ p["w"] + p["b"] - yy) ** 2))
+
+rng = np.random.default_rng(5)
+p0 = {"w": rng.normal(0, 0.3, (4, 6, 8)).astype(np.float32),
+      "b": np.zeros((4, 8), np.float32)}
+rb = (rng.normal(0, 1, (2, 3, 4, 5, 6)).astype(np.float32),  # [R, T, N, B, D]
+      rng.normal(0, 1, (2, 3, 4, 5, 8)).astype(np.float32))
+rval = (rng.normal(0, 1, (4, 5, 6)).astype(np.float32),
+        rng.normal(0, 1, (4, 5, 8)).astype(np.float32))
+icfg = SwarmConfig(n_nodes=4, sync_every=3, topology="ring", merge="fedavg",
+                   lora_only=False, val_threshold=0.0)
+ikw = dict(params=p0, stacked=True, data_sizes=[1.0, 3.0, 3.0, 3.0])
+ses = SwarmSession(icfg, lin_step, lin_eval, **ikw)
+sgs = SwarmSession(icfg, lin_step, lin_eval, backend="gossip", mesh=mesh,
+                   axis="node", param_specs={"w": P(None, "model"),
+                                             "b": P("model")}, **ikw)
+# the round program, before it runs: no site's weights gathered whole
+coll = hlo_stats.collective_bytes(sgs._rounds_jit.lower(
+    sgs.state, rb, rval).compile().as_text())
+assert coll["all-gather"] < 6 * 8 * 4, coll
+elog, glog = ses.run_rounds(rb, rval), sgs.run_rounds(rb, rval)
+np.testing.assert_array_equal(np.asarray(glog["gates"]),
+                              np.asarray(elog["gates"]))
+for k in ("w", "b"):
+    np.testing.assert_allclose(np.asarray(sgs.state.params[k]),
+                               np.asarray(ses.state.params[k]),
+                               rtol=1e-5, atol=1e-6)
+assert sgs.state.params["w"].sharding.shard_shape((4, 6, 8)) == (1, 6, 4)
+print("OK session_inner_specs")
+
 # --- production mesh guard ----------------------------------------------
 from repro.launch.mesh import make_production_mesh
 try:
@@ -310,6 +352,14 @@ def test_full_fedavg_mask_stays_on_psum_schedule(spmd_out):
     fedavg from the psum schedule (2·P·(N−1)/N) to an N·P all_gather: the
     weights are active-masked in-graph and absent nodes keep their params."""
     assert "OK full_psum_masked" in spmd_out
+
+
+def test_session_keeps_inner_sharded_params_sharded(spmd_out):
+    """A gossip session on a (node, model) mesh with inner param specs
+    leaves each site's step to the partitioner: it matches the engine
+    backend, gathers no site's weights whole, and its params stay split
+    over the model axis."""
+    assert "OK session_inner_specs" in spmd_out
 
 
 def test_production_mesh_requires_devices(spmd_out):
