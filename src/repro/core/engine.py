@@ -104,6 +104,11 @@ from repro.core.lora import combine, split_adapters
 from repro.faults.signals import flip_payload_bits
 from repro.kernels.fused_merge import (DEFAULT_BLOCK, fused_merge_tree,
                                        fused_quant_merge_tree)
+from repro.tracing import ROUND_SCOPES
+
+# device names of the round's stages (`tracing.ROUND_SCOPES`): metadata
+# only, they change no arithmetic and no fusion
+LOCAL_STEPS, PROPOSE, GATE, COMMIT = ROUND_SCOPES
 
 
 def default_interpret() -> bool:
@@ -486,7 +491,8 @@ class SwarmEngine:
             return (p2, o2, st, s + 1), m
 
         init = (params, opt_state, stats, jnp.asarray(step0, jnp.int32))
-        (p, o, st, _), metrics = jax.lax.scan(body, init, batches)
+        with jax.named_scope(LOCAL_STEPS):
+            (p, o, st, _), metrics = jax.lax.scan(body, init, batches)
         return p, o, st, metrics
 
     # -- propose -------------------------------------------------------------
@@ -755,95 +761,100 @@ class SwarmEngine:
                 "lower corrupt events to drops instead "
                 "(FaultPlan.lower(corrupt_in_graph=False))")
         log = {}
-        if use_wire:
-            if self._split_lora:
-                payload, base = split_adapters(params)
-            else:
-                payload, base = params, None
-            # θ̂' — what every peer reconstructs from this round's wire
-            # traffic; also next round's reference (EF: the residual θ−θ̂'
-            # is exactly this round's quantization error)
-            eff_payload = comms.wire_effective(payload, wire, self.wire_dtype,
-                                               self.wire_block)
-            if faults is not None:
-                # sender-side checksum of the honest reconstruction, then
-                # the (deterministic, seeded) wire damage, then the
-                # receiver-side checksum: a mismatch quarantines the sender
-                # for this round exactly like an absence.
-                sent = comms.payload_checksum(eff_payload)
-                eff_payload = flip_payload_bits(eff_payload, faults.corrupt,
-                                                faults.key)
-                wire_ok = jnp.equal(sent, comms.payload_checksum(eff_payload))
-                a = a & wire_ok
-                log["wire_ok"] = wire_ok
-            eff = (combine(eff_payload, base) if base is not None
-                   else eff_payload)
-            fishers = None
-            if self.strategy.uses_stats:
-                f = (stats if stats is not None
-                     else jax.tree.map(jnp.zeros_like, params))
-                f = self.strategy.finalize_mass(f, a)
+        with jax.named_scope(PROPOSE):
+            if use_wire:
                 if self._split_lora:
-                    # only the payload's mass crosses the wire — don't burn
-                    # a full-model quantize pass on base leaves propose will
-                    # immediately discard
-                    f = split_adapters(f)[0]
-                # importance mass crosses the wire too (stateless round-trip:
-                # mass errors cancel in the merge ratio, no EF state needed;
-                # propose re-finalizes, which only rescales — the merge
-                # ratio is scale-free)
-                fishers = comms.quant_dequant_tree(f, self.wire_dtype,
-                                                   self.wire_block)
-            candidate, W, imp = self.propose(eff, a, fishers=fishers,
-                                             stats=None)
-        elif use_mesh_wire:
-            # sharded mesh EF wire: the q8 collective schedule quantizes,
-            # exchanges, and reconstructs in-graph; stats are the raw
-            # importance accumulators (finalized inside _propose_gossip)
-            candidate, new_mesh_wire = self._propose_gossip(
-                params, active, stats, wire)
-            W = imp = None
-            log["wire"] = new_mesh_wire
-        else:
-            candidate, W, imp = self.propose(params, active, stats=stats)
-        metric_local = jnp.where(a, self._veval(params, val), 1.0)
-        metric_merged = jnp.where(a, self._veval(candidate, val), 0.0)
-        gates = gate_decisions(metric_merged, metric_local,
-                               self.cfg.val_threshold) & a
-        q = self.quorum
-        if q > 0:
-            # degradation policy: below quorum the whole round holds locals
-            # — every gate closes and the sync is a no-op commit. In-graph
-            # on the runtime mask, so membership swings never retrace.
-            quorum_ok = jnp.sum(a.astype(jnp.int32)) >= q
-            gates = gates & quorum_ok
-            log["quorum_ok"] = quorum_ok
-        if self.fairness_floor > 0.0:
-            # per-site fairness floor (docs/heterogeneous.md): the merged
-            # candidate must clear cfg.gate_metric at EVERY active site or
-            # the whole swarm holds its locals — a commit that helps the
-            # average while degrading the worst site never lands. Inactive
-            # sites read as 1.0 so they never drag the min; in-graph on the
-            # traced metrics, so metric/membership swings never retrace.
-            worst = jnp.min(jnp.where(a, metric_merged, 1.0))
-            fair_ok = worst >= self.fairness_floor
-            gates = gates & fair_ok
-            log["fairness_ok"] = fair_ok
-            log["worst_site"] = worst
-        if use_wire:
-            committed_payload, new_wire = fused_quant_merge_tree(
-                payload, wire, W, gates, imp=imp,
-                wire_dtype=self.wire_dtype, wire_block=self.wire_block,
-                block=self.block, interpret=self.interpret)
-            committed = (combine(committed_payload, base)
-                         if base is not None else committed_payload)
-            log["wire"] = new_wire
-        elif self.backend == "host":
-            committed = host_commit(params, candidate, W, gates, self.cfg,
-                                    imp=imp, block=self.block,
-                                    interpret=self.interpret)
-        else:
-            committed = gated_commit(candidate, params, gates)
+                    payload, base = split_adapters(params)
+                else:
+                    payload, base = params, None
+                # θ̂' — what every peer reconstructs from this round's
+                # wire traffic; also next round's reference (EF: the
+                # residual θ−θ̂' is exactly this round's quantization error)
+                eff_payload = comms.wire_effective(
+                    payload, wire, self.wire_dtype, self.wire_block)
+                if faults is not None:
+                    # sender-side checksum of the honest reconstruction,
+                    # then the (deterministic, seeded) wire damage, then the
+                    # receiver-side checksum: a mismatch quarantines the
+                    # sender for this round exactly like an absence.
+                    sent = comms.payload_checksum(eff_payload)
+                    eff_payload = flip_payload_bits(
+                        eff_payload, faults.corrupt, faults.key)
+                    wire_ok = jnp.equal(sent,
+                                        comms.payload_checksum(eff_payload))
+                    a = a & wire_ok
+                    log["wire_ok"] = wire_ok
+                eff = (combine(eff_payload, base) if base is not None
+                       else eff_payload)
+                fishers = None
+                if self.strategy.uses_stats:
+                    f = (stats if stats is not None
+                         else jax.tree.map(jnp.zeros_like, params))
+                    f = self.strategy.finalize_mass(f, a)
+                    if self._split_lora:
+                        # only the payload's mass crosses the wire — don't
+                        # burn a full-model quantize pass on base leaves
+                        # propose will immediately discard
+                        f = split_adapters(f)[0]
+                    # importance mass crosses the wire too (stateless
+                    # round-trip: mass errors cancel in the merge ratio, no
+                    # EF state needed; propose re-finalizes, which only
+                    # rescales — the merge ratio is scale-free)
+                    fishers = comms.quant_dequant_tree(f, self.wire_dtype,
+                                                       self.wire_block)
+                candidate, W, imp = self.propose(eff, a, fishers=fishers,
+                                                 stats=None)
+            elif use_mesh_wire:
+                # sharded mesh EF wire: the q8 collective schedule quantizes,
+                # exchanges, and reconstructs in-graph; stats are the raw
+                # importance accumulators (finalized inside _propose_gossip)
+                candidate, new_mesh_wire = self._propose_gossip(
+                    params, active, stats, wire)
+                W = imp = None
+                log["wire"] = new_mesh_wire
+            else:
+                candidate, W, imp = self.propose(params, active, stats=stats)
+        with jax.named_scope(GATE):
+            metric_local = jnp.where(a, self._veval(params, val), 1.0)
+            metric_merged = jnp.where(a, self._veval(candidate, val), 0.0)
+            gates = gate_decisions(metric_merged, metric_local,
+                                   self.cfg.val_threshold) & a
+            q = self.quorum
+            if q > 0:
+                # degradation policy: below quorum the whole round holds
+                # locals — every gate closes and the sync is a no-op commit.
+                # In-graph on the runtime mask, so membership swings never
+                # retrace.
+                quorum_ok = jnp.sum(a.astype(jnp.int32)) >= q
+                gates = gates & quorum_ok
+                log["quorum_ok"] = quorum_ok
+            if self.fairness_floor > 0.0:
+                # per-site fairness floor (docs/heterogeneous.md): the merged
+                # candidate must clear cfg.gate_metric at EVERY active site or
+                # the whole swarm holds its locals — a commit that helps the
+                # average while degrading the worst site never lands. Inactive
+                # sites read as 1.0 so they never drag the min; in-graph on the
+                # traced metrics, so metric/membership swings never retrace.
+                worst = jnp.min(jnp.where(a, metric_merged, 1.0))
+                fair_ok = worst >= self.fairness_floor
+                gates = gates & fair_ok
+                log["fairness_ok"] = fair_ok
+                log["worst_site"] = worst
+        with jax.named_scope(COMMIT):
+            if use_wire:
+                committed_payload, new_wire = fused_quant_merge_tree(
+                    payload, wire, W, gates, imp=imp,
+                    wire_dtype=self.wire_dtype, wire_block=self.wire_block,
+                    block=self.block, interpret=self.interpret)
+                committed = (combine(committed_payload, base)
+                             if base is not None else committed_payload)
+                log["wire"] = new_wire
+            elif self.backend == "host":
+                committed = host_commit(params, candidate, W, gates, self.cfg,
+                                        imp=imp, block=self.block,
+                                        interpret=self.interpret)
+            else:
+                committed = gated_commit(candidate, params, gates)
         return committed, dict(log, gates=gates, metric_local=metric_local,
                                metric_merged=metric_merged)
 

@@ -46,6 +46,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
+from repro import tracing
 from repro.checkpointing import load_metadata, load_pytree, save_pytree
 from repro.configs.base import SwarmConfig
 from repro.core import comms, gossip
@@ -151,6 +152,9 @@ class SwarmSession:
         self.backend = backend
         self.train_step_fn = train_step_fn
         self.eval_fn = eval_fn
+        # rounds this object has dispatched, counted on the host: the ``id``
+        # of its `tracing` spans (the device's ``state.round`` is never read)
+        self._dispatched = 0
         n = cfg.n_nodes
         if stacked:
             stacked_params, stacked_opt = params, opt_state
@@ -464,37 +468,44 @@ class SwarmSession:
         wire only — see `SwarmEngine.sync`). Thread a signal (possibly
         `faults.idle_signals`) every round to keep one compiled trace.
         """
-        if self.backend == "host":
-            if faults is not None:
-                raise ValueError(
-                    "in-graph fault injection (faults=) needs a compiled "
-                    "backend; lower corrupt events to drops on the host loop")
-            return self._host_round(batches, val)
-        self._state, out = self._round_jit(
-            self._state, self._on_mesh(batches, 1), self._on_mesh(val), faults)
-        return out
+        if self.backend == "host" and faults is not None:
+            raise ValueError(
+                "in-graph fault injection (faults=) needs a compiled "
+                "backend; lower corrupt events to drops on the host loop")
+        with tracing.span("round", id=self._dispatched):
+            self._dispatched += 1
+            if self.backend == "host":
+                return self._host_round(batches, val)
+            self._state, out = self._round_jit(
+                self._state, self._on_mesh(batches, 1), self._on_mesh(val),
+                faults)
+            return out
 
     def run_rounds(self, batches, val):
         """R rounds over ``[R, T, N, ...]`` batches, scanned on-device
         (engine/gossip) or looped (host). Returns per-round logs — stacked
         [R, ...] arrays with a ``train`` key on engine/gossip; per-key lists
         of the R host round logs (see :meth:`round`) on host."""
-        if self.backend == "host":
-            logs = [self._host_round(rb, val) for rb in batches]
-            return {k: [lg[k] for lg in logs] for k in logs[0]}
-        self._state, tm, logs = self._rounds_jit(
-            self._state, self._on_mesh(batches, 2), self._on_mesh(val))
-        return dict(logs, train=tm)
+        with tracing.span("round", id=self._dispatched):
+            if self.backend == "host":
+                self._dispatched += len(batches)
+                logs = [self._host_round(rb, val) for rb in batches]
+                return {k: [lg[k] for lg in logs] for k in logs[0]}
+            self._dispatched += jax.tree.leaves(batches)[0].shape[0]
+            self._state, tm, logs = self._rounds_jit(
+                self._state, self._on_mesh(batches, 2), self._on_mesh(val))
+            return dict(logs, train=tm)
 
     def run_local(self, batches):
         """Sync-free local training ([S, N, ...] stacked, or [S][N] host)."""
-        if self.backend == "host":
-            for step_batches in batches:
-                self._learner.local_steps(step_batches)
-            return None
-        self._state, tm = self._local_jit(self._state,
-                                          self._on_mesh(batches, 1))
-        return tm
+        with tracing.span("round", id=self._dispatched):
+            if self.backend == "host":
+                for step_batches in batches:
+                    self._learner.local_steps(step_batches)
+                return None
+            self._state, tm = self._local_jit(self._state,
+                                              self._on_mesh(batches, 1))
+            return tm
 
     def _host_round(self, batches, val):
         lr = self._learner

@@ -18,6 +18,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import tracing
 from repro.configs.base import SwarmConfig, TrainConfig
 from repro.core.session import SwarmSession
 from repro.data import (augment, batches, make_histo_dataset, paper_splits,
@@ -140,7 +141,6 @@ def _swarm_session(ecfg, train_step, shards, swarm_cfg=None, **session_kw):
     backend unless ``session_kw`` names another, e.g.
     ``backend="gossip", mesh=mesh, axis="node"`` for one site per device.
     Without ``swarm_cfg`` the sites never sync (isolated local learners)."""
-    params = _init_params(ecfg, jax.random.key(ecfg.seed + 42))
     cfg = swarm_cfg or SwarmConfig(n_nodes=len(shards), sync_every=10**9,
                                    gate_metric="auc")
     metric = gate_metric_fn(cfg.gate_metric)
@@ -149,9 +149,12 @@ def _swarm_session(ecfg, train_step, shards, swarm_cfg=None, **session_kw):
         x, y, m = v
         return metric(jax.nn.sigmoid(forward_cnn(p, x)), y, m)
 
-    return SwarmSession(cfg, train_step, eval_fn, params=params,
-                        opt_state=adamw_init(params), seed=ecfg.seed,
-                        data_sizes=[len(y) for _, y in shards], **session_kw)
+    with tracing.span("session.build"):
+        params = _init_params(ecfg, jax.random.key(ecfg.seed + 42))
+        return SwarmSession(cfg, train_step, eval_fn, params=params,
+                            opt_state=adamw_init(params), seed=ecfg.seed,
+                            data_sizes=[len(y) for _, y in shards],
+                            **session_kw)
 
 
 def _train_loop(ecfg, train_step, shards, *, swarm_cfg=None, log=None,

@@ -1,0 +1,139 @@
+"""The program's host spans, kept in memory, and the names of the round's
+stages on the device.
+
+Host spans: ``with tracing.span("round", id=r): ...`` records a `Span` while
+the recorder is on (`enable`); `drain` hands the recorded spans over and
+empties the list. Off, the default, `span` returns one shared no-op context
+manager: no clock is read and nothing is kept. Start and end come from
+`time.time_ns`, the clock a profiler trace is put on with its
+``profile_start_time``, so spans and device events line up; ``cpu_ns`` is
+the thread's CPU time over the span (`time.thread_time_ns`), which tells
+host work from waiting.
+
+Device stages: `core.engine` wraps the round's four stages in
+`jax.named_scope` under the names in `ROUND_SCOPES`. XLA keeps a scope in
+each instruction's ``metadata={op_name="…/swarm.gate/…"}``;
+`scope_of_ops` reads it back from a compiled module's text
+(``Compiled.as_text()``), so the ops of a device trace, which are named
+by their HLO names, can be summed per stage.
+"""
+from __future__ import annotations
+
+import re
+import threading
+import time
+from dataclasses import dataclass
+from typing import Optional
+
+ROUND_SCOPES = ("swarm.local_steps", "swarm.propose", "swarm.gate",
+                "swarm.commit")
+
+
+@dataclass
+class Span:
+    name: str
+    id: Optional[int]
+    parent: Optional[int]  # index of the enclosing span in the same list
+    start_ns: int
+    end_ns: int = 0
+    cpu_ns: int = 0        # the thread's CPU time between start and end
+
+
+class _Off:
+    """What `span` returns while the recorder is off."""
+
+    def __enter__(self):
+        return None
+
+    def __exit__(self, *exc):
+        return False
+
+
+_OFF = _Off()
+
+
+class _Timed:
+    __slots__ = ("rec", "span", "cpu0")
+
+    def __init__(self, rec: "Recorder", name: str, id: Optional[int]):
+        self.rec = rec
+        self.span = Span(name, id, None, 0)
+
+    def __enter__(self):
+        stack = self.rec._stack()
+        self.span.parent = stack[-1] if stack else None
+        with self.rec._lock:
+            stack.append(len(self.rec.spans))
+            self.rec.spans.append(self.span)
+        self.span.start_ns = time.time_ns()
+        self.cpu0 = time.thread_time_ns()
+        return self.span
+
+    def __exit__(self, *exc):
+        self.span.cpu_ns = time.thread_time_ns() - self.cpu0
+        self.span.end_ns = time.time_ns()
+        self.rec._stack().pop()
+        return False
+
+
+class Recorder:
+    """Spans of every thread in one list, in the order they opened; each
+    thread's open spans on a stack of its own (a span's parent is the span
+    open on its thread when it opened). `drain` only between spans: a span
+    opened before a drain and closed after it belongs to the old list."""
+
+    def __init__(self):
+        self.on = False
+        self.spans: list[Span] = []
+        self._lock = threading.Lock()
+        self._local = threading.local()
+
+    def _stack(self) -> list:
+        stack = getattr(self._local, "stack", None)
+        if stack is None:
+            stack = self._local.stack = []
+        return stack
+
+    def span(self, name: str, id: Optional[int] = None):
+        """A context manager that records ``name`` while the recorder is
+        on; the shared no-op `_OFF` while it is off."""
+        if not self.on:
+            return _OFF
+        return _Timed(self, name, id)
+
+    def enable(self) -> None:
+        self.on = True
+
+    def disable(self) -> None:
+        self.on = False
+
+    def drain(self) -> list[Span]:
+        """The spans recorded so far; the list starts again empty."""
+        with self._lock:
+            out, self.spans = self.spans, []
+        return out
+
+
+_recorder = Recorder()
+span = _recorder.span
+enable = _recorder.enable
+disable = _recorder.disable
+drain = _recorder.drain
+
+_INSTR = re.compile(r"^\s*(?:ROOT\s+)?%?([\w.\-]+) = .*?"
+                    r"metadata=\{[^}]*?op_name=\"([^\"]*)\"", re.M)
+_SCOPE = re.compile(r"(?:^|/)(" + "|".join(map(re.escape, ROUND_SCOPES))
+                    + r")(?=/|$)")
+
+
+def scope_of_ops(hlo_text: str) -> dict[str, str]:
+    """``{HLO instruction name: round scope}`` for every instruction of a
+    compiled module whose ``op_name`` lies under one of `ROUND_SCOPES`
+    (the innermost, should they nest). Instructions of no scope, such as
+    copies XLA adds, are left out."""
+    out = {}
+    for name, op_name in _INSTR.findall(hlo_text):
+        scopes = _SCOPE.findall(op_name)
+        if scopes:
+            out[name] = scopes[-1]
+    return out
