@@ -7,7 +7,10 @@ nodes: every sync unstacked N param copies, ran per-node ``eval_fn`` with
 This module compiles the round end-to-end over **stacked pytrees** (leading
 node axis N):
 
-  local steps   ``jax.vmap`` of the user train step over the node axis,
+  local steps   ``jax.vmap`` of the user train step over the node axis, or
+                the stacked form the step offers where one TPU holds
+                several sites (``train_step_fn.stacked``; the histo step
+                folds the sites into the DenseNet's lanes),
                 ``jax.lax.scan`` over the ``sync_every`` time axis; the
                 configured `merge_impl.MergeStrategy` accumulates per-node
                 importance statistics (Fisher mass) in the same scan;
@@ -15,7 +18,8 @@ node axis N):
                 weighted merge (host backend) / mesh collectives (gossip
                 backend, `core.gossip`) — every merge method in-graph;
   gate          in-graph validation metrics for local AND merged params
-                (``jax.vmap`` of a traceable ``eval_fn``) → per-node accept
+                (``jax.vmap`` of a traceable ``eval_fn``, or its stacked
+                form as for the step) → per-node accept
                 bits — no host scalar sync anywhere in the round;
   commit        `kernels.fused_merge.fused_merge_tree`: the Pallas kernel
                 fuses contraction-over-nodes (W rows, optionally importance-
@@ -104,16 +108,24 @@ from repro.core.lora import combine, split_adapters
 from repro.faults.signals import flip_payload_bits
 from repro.kernels.fused_merge import (DEFAULT_BLOCK, fused_merge_tree,
                                        fused_quant_merge_tree)
-from repro.tracing import ROUND_SCOPES
+from repro import tracing
 
 # device names of the round's stages (`tracing.ROUND_SCOPES`): metadata
 # only, they change no arithmetic and no fusion
-LOCAL_STEPS, PROPOSE, GATE, COMMIT = ROUND_SCOPES
+LOCAL_STEPS, PROPOSE, GATE, COMMIT = tracing.ROUND_SCOPES
 
 
 def default_interpret() -> bool:
     """Pallas interpret mode when no TPU is attached (validation mode)."""
     return jax.default_backend() != "tpu"
+
+
+def lanes_tiled() -> bool:
+    """Whether arrays sit in 128-lane tiles (the TPU), so that stacked
+    forms that fold several sites into the lanes (`histo`'s) save the
+    padding of each site's narrow channel axis; elsewhere their
+    block-diagonal weights only multiply the work."""
+    return jax.default_backend() == "tpu"
 
 
 # ---------------------------------------------------------------------------
@@ -432,16 +444,40 @@ class SwarmEngine:
                                  f"(got {len(fns)}, n_nodes={cfg.n_nodes})")
             return fns
 
-        if isinstance(train_step_fn, (list, tuple)):
+        # A step or eval may offer a stacked form as its ``stacked``
+        # attribute: every site in one call, stacked in and out, returning
+        # what its vmap returns (`histo._make_model_fns` folds the sites
+        # into the DenseNet's lanes). It is taken where one TPU holds
+        # several sites; zoo lists and the gossip backend keep their paths.
+        fold = backend == "host" and cfg.n_nodes > 1 and lanes_tiled()
+
+        def _form(fn):
+            if fn is None:
+                return None
+            if isinstance(fn, (list, tuple)):
+                return "zoo"
+            return "stacked" if fold and hasattr(fn, "stacked") else "vmap"
+
+        self.forms = {LOCAL_STEPS: _form(train_step_fn),
+                      GATE: _form(eval_fn)}
+        form = self.forms[LOCAL_STEPS]
+        if form == "zoo":
             self._vstep = zoo_vstep(_fn_list(train_step_fn, "train_step_fn"))
+        elif form == "stacked":
+            self._vstep = train_step_fn.stacked
         else:
             self._vstep = (None if train_step_fn is None
                            else jax.vmap(train_step_fn,
                                          in_axes=(0, 0, 0, None)))
-        if isinstance(eval_fn, (list, tuple)):
+        form = self.forms[GATE]
+        if form == "zoo":
             self._veval = zoo_veval(_fn_list(eval_fn, "eval_fn"))
+        elif form == "stacked":
+            self._veval = eval_fn.stacked
         else:
             self._veval = None if eval_fn is None else jax.vmap(eval_fn)
+        tracing.annotate(forms=self.forms, folded_sites=(
+            cfg.n_nodes if "stacked" in self.forms.values() else 0))
         from repro.core import gossip
         if backend == "gossip" and gossip.spans_mesh(mesh, axis):
             # each device steps and scores its own sites (gossip.per_shard);
@@ -815,8 +851,19 @@ class SwarmEngine:
             else:
                 candidate, W, imp = self.propose(params, active, stats=stats)
         with jax.named_scope(GATE):
-            metric_local = jnp.where(a, self._veval(params, val), 1.0)
-            metric_merged = jnp.where(a, self._veval(candidate, val), 0.0)
+            if self.forms[GATE] == "stacked":
+                # both scorings through one loop body: the device holds one
+                # copy of the stacked forward's code, which is larger than
+                # the vmapped one's
+                pair = jax.tree.map(lambda *x: jnp.stack(x), params,
+                                    candidate)
+                local, merged = jax.lax.map(lambda p: self._veval(p, val),
+                                            pair)
+            else:
+                local = self._veval(params, val)
+                merged = self._veval(candidate, val)
+            metric_local = jnp.where(a, local, 1.0)
+            metric_merged = jnp.where(a, merged, 0.0)
             gates = gate_decisions(metric_merged, metric_local,
                                    self.cfg.val_threshold) & a
             q = self.quorum

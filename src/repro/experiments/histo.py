@@ -24,7 +24,8 @@ from repro.core.session import SwarmSession
 from repro.data import (augment, batches, make_histo_dataset, paper_splits,
                         shard_to_nodes)
 from repro.metrics import classify_report, davies_bouldin, gate_metric_fn
-from repro.models.cnn import bce_loss, forward_cnn, init_cnn
+from repro.models.cnn import (TRAIN_FOLD_SHARE, bce_loss, forward_cnn,
+                              forward_cnn_sites, init_cnn)
 from repro.optim import EarlyStopper, adamw_init, adamw_update, make_schedule
 
 
@@ -63,13 +64,37 @@ def _make_model_fns(ecfg: HistoExperimentConfig):
     def loss(params, x, y):
         return bce_loss(forward_cnn(params, x), jax.nn.one_hot(y, 3))
 
+    def site_losses(params, x, y):
+        logits = forward_cnn_sites(params, x, fold_share=TRAIN_FOLD_SHARE)
+        losses = jax.vmap(bce_loss)(logits, jax.nn.one_hot(y, 3))
+        return losses.sum(), losses
+
+    def update(params, g, opt_state):
+        return adamw_update(params, g, opt_state, tc,
+                            sched(opt_state["count"]))
+
     @jax.jit
     def train_step(params, opt_state, batch, step):
         x, y = batch
         l, g = jax.value_and_grad(loss)(params, jnp.asarray(x), jnp.asarray(y))
-        params, opt_state = adamw_update(params, g, opt_state, tc,
-                                         sched(opt_state["count"]))
+        params, opt_state = update(params, g, opt_state)
         return params, opt_state, {"loss": l}
+
+    @jax.jit
+    def train_step_sites(params, opt_state, batch, step):
+        """`train_step` of N sites stacked on a leading axis, on the
+        site-folded forward: the gradient of the sum of the sites' losses
+        is each site's own gradient, and AdamW (its clipping included) runs
+        per site."""
+        x, y = batch
+        (_, l), g = jax.value_and_grad(site_losses, has_aux=True)(
+            params, jnp.asarray(x), jnp.asarray(y))
+        params, opt_state = jax.vmap(update)(params, g, opt_state)
+        return params, opt_state, {"loss": l}
+
+    # the engine runs this in place of vmap(train_step) where one device
+    # holds several sites (`SwarmEngine`); alone, train_step stays per site
+    train_step.stacked = train_step_sites
 
     @jax.jit
     def predict(params, x):
@@ -148,6 +173,12 @@ def _swarm_session(ecfg, train_step, shards, swarm_cfg=None, **session_kw):
     def eval_fn(p, v):
         x, y, m = v
         return metric(jax.nn.sigmoid(forward_cnn(p, x)), y, m)
+
+    def eval_sites(p, v):  # every site at once, as train_step.stacked
+        x, y, m = v
+        return jax.vmap(metric)(jax.nn.sigmoid(forward_cnn_sites(p, x)), y, m)
+
+    eval_fn.stacked = eval_sites
 
     with tracing.span("session.build"):
         params = _init_params(ecfg, jax.random.key(ecfg.seed + 42))

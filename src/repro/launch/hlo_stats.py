@@ -69,6 +69,83 @@ def collective_bytes(hlo_text: str) -> Dict[str, int]:
     return out
 
 
+# --- tile padding (TPU layouts) ---------------------------------------------
+# A TPU array is stored in tiles over its most minor dims: ``T(8,128)`` rounds
+# the minor dim up to 128 lanes and the one above it to 8 sublanes. A shape
+# whose minor dim is 32 is stored at 4x its size. `tile_padding` sums, over
+# the instructions of the computations that run unfused (the entry, loop
+# bodies), the bytes of each result and operand as stored and as logical.
+
+_ARRAY_RE = re.compile(r"\b([a-z][a-z0-9]*)\[([0-9,]*)\](?:\{([0-9,]*)"
+                       r"(?::([^}]*))?\})?")
+_TILE_RE = re.compile(r"T\(([0-9,]+)\)")
+_INSTR_RE = re.compile(r"^\s*(?:ROOT\s+)?%([\w.\-]+) = (.*?) ([\w\-]+)\((.*)$")
+_COMP_RE = re.compile(r"^(ENTRY\s+)?%([\w.\-]+) .*\{\s*$")
+_CALLED_RE = re.compile(r"\b(?:calls|to_apply)=%([\w.\-]+)")
+# no bytes move: names, views, bookkeeping
+_NO_BYTES = {"parameter", "constant", "get-tuple-element", "tuple",
+             "bitcast", "after-all", "copy-start", "copy-done", "partition-id",
+             "replica-id", "iota"}
+
+
+def _array_bytes(type_str: str):
+    """(stored, logical) bytes of every array in ``type_str``."""
+    stored = logical = 0
+    for dt, dims, layout, tiling in _ARRAY_RE.findall(
+            _COMMENT_RE.sub("", type_str)):
+        if dt not in _DTYPE_BYTES:
+            continue
+        shape = [int(d) for d in dims.split(",") if d]
+        n = 1
+        for d in shape:
+            n *= d
+        logical += n * _DTYPE_BYTES[dt]
+        tile = _TILE_RE.search(tiling or "")
+        if tile and layout:
+            minor_to_major = [int(d) for d in layout.split(",") if d]
+            padded = list(shape)
+            for t, dim in zip(reversed(tile.group(1).split(",")),
+                              minor_to_major):
+                t = int(t)
+                padded[dim] = -(-padded[dim] // t) * t
+            n = 1
+            for d in padded:
+                n *= d
+        stored += n * _DTYPE_BYTES[dt]
+    return stored, logical
+
+
+def tile_padding(hlo_text: str):
+    """(stored, logical) bytes of the results and operands of every
+    instruction in the unfused computations of a compiled module's text
+    (``Compiled.as_text()``); fusion bodies and reducers are left out, and
+    so are instructions that move no bytes (`_NO_BYTES`)."""
+    types, comps, current = {}, {}, None
+    for line in hlo_text.splitlines():
+        c = _COMP_RE.match(line)
+        if c:
+            current = comps.setdefault(c.group(2), [])
+            continue
+        m = _INSTR_RE.match(line)
+        if m and current is not None:
+            types[m.group(1)] = m.group(2)
+            current.append(m)
+    called = set(_CALLED_RE.findall(hlo_text))
+    stored = logical = 0
+    for name, instrs in comps.items():
+        if name in called:
+            continue
+        for m in instrs:
+            if m.group(3) in _NO_BYTES:
+                continue
+            operands = re.findall(r"%([\w.\-]+)", m.group(4).split("), ")[0])
+            for t in [m.group(2)] + [types.get(o, "") for o in operands]:
+                s, l = _array_bytes(t)
+                stored += s
+                logical += l
+    return stored, logical
+
+
 # --- per-link-class split (two-level ("pod", "node") meshes) ----------------
 # A collective participates in exactly one link class: "intra" when every one
 # of its device groups (or source→target pairs) stays inside a single pod,

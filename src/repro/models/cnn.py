@@ -105,3 +105,108 @@ def bce_loss(logits, labels_onehot):
     logp = jax.nn.log_sigmoid(logits)
     lognp = jax.nn.log_sigmoid(-logits)
     return -jnp.mean(labels_onehot * logp + (1 - labels_onehot) * lognp)
+
+
+# ---------------------------------------------------------------------------
+# site-folded forward: N sites' models in the lanes of one activation
+# ---------------------------------------------------------------------------
+# `jax.vmap(forward_cnn)` keeps each site's channels as the minor (lane)
+# axis of its activations; the TPU stores that axis in 128-lane tiles, and
+# the paper's widths (growth 32, stem 64, blocks of 96-248) leave much of
+# each tile as padding. The folded forward carries the sites inside the
+# lane axis instead: activations ``[B, H, W, C·N]`` with the site as the
+# minor index of each channel, so N = 4 growth outputs fill 128 lanes and
+# the dense blocks' concatenations grow by whole lane groups. Batch norm
+# and pooling act per lane, which is per site and channel; only the
+# convolutions mix lanes, through weights that are block-diagonal across
+# sites.
+#
+# Which stages run folded is chosen by shape. A forward alone (the gate)
+# folds them all. A training step folds the stem and the encoder modules
+# whose input keeps more than 1/TRAIN_FOLD_SHARE of the image's pixels (at
+# 224 px the stem and the first module, which hold most of the activation
+# bytes) and vmaps the smaller ones: each folded convolution's compiled
+# code, forward and backward, is about twice the vmapped one's, the device
+# holds that code in its memory, and the bytes folding saves shrink with
+# the activations.
+
+TRAIN_FOLD_SHARE = 64
+
+
+def fold_sites(x):
+    """``[N, ..., C]`` -> ``[..., C·N]``, the site the minor index."""
+    return jnp.moveaxis(x, 0, -1).reshape(x.shape[1:-1] + (-1,))
+
+
+def unfold_sites(x, n: int):
+    """Inverse of `fold_sites`: ``[..., C·N]`` -> ``[N, ..., C]``."""
+    return jnp.moveaxis(x.reshape(x.shape[:-1] + (-1, n)), -1, 0)
+
+
+def conv2d_sites(w, x, stride=1, padding="SAME"):
+    """Per-site convolution on folded activations: ``w [N, kh, kw, cin,
+    cout]``, ``x [B, H, W, cin·N]`` -> ``[B, H', W', cout·N]``, as one
+    convolution whose weight is zero between sites."""
+    n, kh, kw, cin, cout = w.shape
+    rows = jnp.moveaxis(w, 0, 3).reshape(kh, kw, cin * n, cout)
+    site_in = jnp.arange(cin * n) % n
+    site_out = jnp.arange(cout * n) % n
+    block_diag = jnp.where(site_in[:, None] == site_out[None, :],
+                           jnp.repeat(rows, n, axis=-1), 0.0)
+    return conv2d(block_diag, x, stride, padding)
+
+
+def _bn_sites(p):
+    """Per-site batch-norm params ``[N, C]`` -> per-lane ``[C·N]``."""
+    return jax.tree.map(lambda v: v.T.reshape(-1), p)
+
+
+def _dense_block(block, x, conv, bn):
+    """One encoder module of `forward_cnn`, given its convolution and the
+    layout of its batch-norm params."""
+    for layer in block["layers"]:
+        h = jax.nn.relu(batchnorm(bn(layer["bn"]), x))
+        x = jnp.concatenate([x, conv(layer["w"], h)], axis=-1)
+    x = jax.nn.relu(batchnorm(bn(block["trans"]["bn"]), x))
+    x = conv(block["trans"]["w"], x)
+    if min(x.shape[1], x.shape[2]) >= 2:
+        x = jax.lax.reduce_window(x, 0.0, jax.lax.add, (1, 2, 2, 1),
+                                  (1, 2, 2, 1), "VALID") / 4.0
+    return x
+
+
+def _site_block(block, x):
+    return _dense_block(block, x, conv2d, lambda p: p)
+
+
+def forward_cnn_sites(params, images, fold_share=None):
+    """`forward_cnn` of N sites at once, on site-folded activations.
+
+    ``params``: per-site params stacked on a leading site axis ``[N, ...]``;
+    ``images [N, B, H, W, 3]`` -> per-site logits ``[N, B, 3]``, what
+    ``jax.vmap(forward_cnn)(params, images)`` returns (up to the order of
+    accumulation: the zero blocks add exact zeros). With ``fold_share``,
+    the encoder modules whose input keeps no more than 1/fold_share of the
+    image's pixels run per site, vmapped, instead."""
+    n, pixels = images.shape[0], images.shape[2] * images.shape[3]
+    x = conv2d_sites(params["stem"]["w"], fold_sites(images), stride=2)
+    x = jax.nn.relu(batchnorm(_bn_sites(params["stem"]["bn"]), x))
+    x = jax.lax.reduce_window(x, -jnp.inf, jax.lax.max, (1, 3, 3, 1),
+                              (1, 2, 2, 1), "SAME")
+    blocks = list(params["blocks"])
+    while blocks and (fold_share is None
+                      or x.shape[1] * x.shape[2] * fold_share > pixels):
+        x = _dense_block(blocks.pop(0), x, conv2d_sites, _bn_sites)
+    # the barrier keeps XLA from moving the unfold's lane shuffle into the
+    # pool before it, where it lays the pool out with a width as lanes
+    x = unfold_sites(jax.lax.optimization_barrier(x), n)
+    for block in blocks:
+        x = jax.vmap(_site_block)(block, x)
+    feats = jnp.mean(x, axis=(2, 3))  # [N, B, feat_dim]
+
+    def head(h, f):
+        z = f @ h["fc1"]["w"] + h["fc1"]["b"]
+        z = jax.nn.relu(batchnorm(h["fc1"]["bn"], z))
+        return batchnorm(h["fc2"]["bn"], z @ h["fc2"]["w"] + h["fc2"]["b"])
+
+    return jax.vmap(head)(params["head"], feats)

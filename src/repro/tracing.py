@@ -8,7 +8,10 @@ manager: no clock is read and nothing is kept. Start and end come from
 `time.time_ns`, the clock a profiler trace is put on with its
 ``profile_start_time``, so spans and device events line up; ``cpu_ns`` is
 the thread's CPU time over the span (`time.thread_time_ns`), which tells
-host work from waiting.
+host work from waiting. `annotate` adds ``attrs`` to the span open on
+the thread: what the program chose inside it, such as the forms of the
+round's local step and gate and the sites they fold
+(`core.engine.SwarmEngine`, inside ``session.build``).
 
 Device stages: `core.engine` wraps the round's four stages in
 `jax.named_scope` under the names in `ROUND_SCOPES`. XLA keeps a scope in
@@ -37,6 +40,7 @@ class Span:
     start_ns: int
     end_ns: int = 0
     cpu_ns: int = 0        # the thread's CPU time between start and end
+    attrs: Optional[dict] = None  # what `annotate` added
 
 
 class _Off:
@@ -101,6 +105,15 @@ class Recorder:
             return _OFF
         return _Timed(self, name, id)
 
+    def annotate(self, **attrs) -> None:
+        """Add ``attrs`` to the innermost span open on this thread: what
+        the program chose inside it, such as the form of a compiled step.
+        Nothing while the recorder is off or no span is open."""
+        stack = self._stack() if self.on else None
+        if stack:
+            sp = self.spans[stack[-1]]
+            sp.attrs = {**(sp.attrs or {}), **attrs}
+
     def enable(self) -> None:
         self.on = True
 
@@ -116,6 +129,7 @@ class Recorder:
 
 _recorder = Recorder()
 span = _recorder.span
+annotate = _recorder.annotate
 enable = _recorder.enable
 disable = _recorder.disable
 drain = _recorder.drain

@@ -31,6 +31,28 @@ def test_parser_simple_ops():
     assert cb["count"] == 5
 
 
+def test_tile_padding_counts_unfused_operands_and_results_as_stored():
+    txt = """
+%fused_computation (param_0: f32[4,32]) -> f32[4,32] {
+  %param_0 = f32[4,32]{1,0:T(8,128)} parameter(0)
+  ROOT %neg.1 = f32[4,32]{1,0:T(8,128)} negate(%param_0)
+}
+
+ENTRY %main (x: f32[4,32]) -> f32[4,160] {
+  %x = f32[4,32]{1,0:T(8,128)} parameter(0)
+  %neg_fusion = f32[4,32]{1,0:T(8,128)} fusion(%x), kind=kLoop, calls=%fused_computation
+  %b = f32[4,32]{1,0:T(8,128)} bitcast(%neg_fusion)
+  ROOT %cat = f32[4,160]{0,1:T(8,128)} concatenate(%b, %x), dimensions={1}
+}
+"""
+    stored, logical = hlo_stats.tile_padding(txt)
+    small = 4 * 32 * 4
+    # the fusion reads and writes [4, 32] stored as [8, 128]; the concat
+    # reads two and writes [4, 160] with dim 0 minor: stored as [160, 128]
+    assert logical == 2 * small + 2 * small + 4 * 160 * 4
+    assert stored == 4 * (8 * 128 * 4) + 160 * 128 * 4
+
+
 def test_parser_tuple_result_and_async():
     txt = """
       %all-reduce = (f32[768,2304]{1,0}, f32[2304]{0}, /*index=5*/f32[10,14]{1,0}) all-reduce(%a, %b, %c)
