@@ -1,5 +1,6 @@
-"""Architecture config registry: the 10 assigned architectures + the paper's
-own histopathology CNN. ``--arch <id>`` anywhere in launch/ resolves here."""
+"""Architecture config registry: the language-model architectures the swarm
+fine-tunes and serves (`ARCHS`); the paper's histopathology CNN is
+`configs.paper_histo`. ``--arch <id>`` anywhere in launch/ resolves here."""
 from __future__ import annotations
 
 from repro.configs.base import (  # noqa: F401
@@ -17,10 +18,11 @@ from repro.configs.minicpm_2b import CONFIG as _minicpm
 from repro.configs.seamless_m4t_medium import CONFIG as _seamless
 from repro.configs.deepseek_coder_33b import CONFIG as _deepseek
 from repro.configs.granite_moe_3b import CONFIG as _granite
+from repro.configs.granite_4_0_h_micro import CONFIG as _granite_h
 
 ARCHS = {c.name: c for c in [
     _internvl2, _commandr, _hymba, _mamba2, _nemotron,
-    _phi35, _minicpm, _seamless, _deepseek, _granite,
+    _phi35, _minicpm, _seamless, _deepseek, _granite, _granite_h,
 ]}
 ARCH_IDS = tuple(ARCHS)
 
@@ -48,7 +50,9 @@ def smoke_variant(cfg: ModelConfig) -> ModelConfig:
         upd.update(n_experts=4, top_k=min(cfg.top_k, 2), d_ff_expert=128)
     if cfg.family in ("ssm", "hybrid"):
         upd.update(ssm_state=min(cfg.ssm_state, 16), ssm_chunk=16,
-                   ssm_head_dim=64, ssm_expand=2)
+                   ssm_head_dim=64, ssm_expand=2, ssm_heads=0)
+    if cfg.layer_kinds:  # one layer of each kind
+        upd.update(layer_kinds=("mamba", "attention"))
     if cfg.is_encdec:
         upd.update(n_enc_layers=2, enc_seq_len=16, frontend_dim=32)
     if cfg.family == "vlm":
@@ -57,10 +61,10 @@ def smoke_variant(cfg: ModelConfig) -> ModelConfig:
 
 
 def adapt_for_shape(cfg: ModelConfig, shape: ShapeConfig) -> ModelConfig:
-    """Per-shape architecture adaptation (DESIGN.md §Arch-applicability):
-    ``long_500k`` on full-attention archs switches on the sliding-window
-    variant (window 4096, periodic global layers disabled) so the attention
-    path is sub-quadratic; ssm/hybrid run natively."""
+    """Per-shape architecture adaptation: ``long_500k`` on full-attention
+    archs switches on the sliding-window variant (window 4096, periodic
+    global layers disabled) so the attention path is sub-quadratic;
+    ssm/hybrid run natively."""
     if shape.name == "long_500k" and cfg.family in ("dense", "moe", "vlm", "audio"):
         if cfg.sliding_window == 0:
             return cfg.replace(sliding_window=4096, attn_every=0)

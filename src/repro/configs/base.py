@@ -11,6 +11,10 @@ from dataclasses import dataclass, field
 from typing import Optional, Tuple
 
 
+# the mixers of an interleaved stack (`ModelConfig.layer_kinds`)
+LAYER_KINDS = ("mamba", "attention")
+
+
 @dataclass(frozen=True)
 class ModelConfig:
     """Architecture description, rich enough for all 6 assigned families."""
@@ -27,7 +31,9 @@ class ModelConfig:
     d_ff: int = 1024
     vocab_size: int = 1000
     head_dim: int = 0          # 0 -> d_model // n_heads
-    activation: str = "swiglu"  # swiglu | sq_relu | gelu
+    # swiglu | swiglu_fused (gate and up in one input projection) | sq_relu
+    # | gelu
+    activation: str = "swiglu"
     use_bias: bool = False
     tie_embeddings: bool = False
     norm_eps: float = 1e-5
@@ -37,6 +43,20 @@ class ModelConfig:
     # attention variant
     sliding_window: int = 0     # 0 = full attention; >0 = window size
     attn_every: int = 0         # hybrid/SWA: full-attn every k-th layer (0=never)
+    use_rope: bool = True       # False: no positional embedding ("nope")
+    # interleaved stack: each layer's mixer, "mamba" or "attention", each
+    # followed by the MLP (empty: one kind of block per family)
+    layer_kinds: Tuple[str, ...] = ()
+    # scalar multipliers (Granite): embeddings times, residual branches
+    # times, logits divided by; the softmax scale (0: 1/sqrt(head_dim))
+    embedding_multiplier: float = 1.0
+    residual_multiplier: float = 1.0
+    logits_scaling: float = 1.0
+    attention_multiplier: float = 0.0
+    # tokens per chunk of the head's logits in the loss and the accuracy,
+    # and query positions per chunk of the attention scores (0: all at once)
+    loss_chunk: int = 0
+    attn_chunk: int = 0
 
     # MoE
     n_experts: int = 0
@@ -73,6 +93,12 @@ class ModelConfig:
     def __post_init__(self):
         if self.head_dim == 0:
             object.__setattr__(self, "head_dim", self.d_model // self.n_heads)
+        if self.layer_kinds and len(self.layer_kinds) != self.n_layers:
+            raise ValueError(f"{len(self.layer_kinds)} layer kinds for "
+                             f"{self.n_layers} layers")
+        if not set(self.layer_kinds) <= set(LAYER_KINDS):
+            raise ValueError(f"layer kinds {set(self.layer_kinds)} not in "
+                             f"{LAYER_KINDS}")
 
     @property
     def padded_vocab(self) -> int:
@@ -100,7 +126,7 @@ class ModelConfig:
         d, f, v = self.d_model, self.d_ff, self.vocab_size
         hd, nh, nkv = self.head_dim, self.n_heads, self.n_kv_heads
         attn = d * hd * (nh + 2 * nkv) + nh * hd * d
-        if self.activation == "swiglu":
+        if self.activation.startswith("swiglu"):
             mlp = 3 * d * f
         else:
             mlp = 2 * d * f
@@ -110,13 +136,19 @@ class ModelConfig:
         ssm = 0
         if self.family in ("ssm", "hybrid"):
             di, ns, nh_s = self.d_inner, self.ssm_state, self.n_ssm_heads
-            g = self.ssm_groups
-            zx = d * (2 * di + 2 * g * ns + nh_s)
-            ssm = zx + self.conv_width * (di + 2 * g * ns) + nh_s * 2 + di * d + di
+            conv_dim = di + 2 * self.ssm_groups * ns
+            # in_proj, depthwise conv and its bias, A_log / D / dt_bias,
+            # the gated norm, out_proj
+            ssm = (d * (di + conv_dim + nh_s) + conv_dim * (self.conv_width + 1)
+                   + 3 * nh_s + di + di * d)
             if self.family == "ssm":
                 attn, mlp = 0, 0
-        block = attn + mlp + ssm + 2 * d
-        n_blocks = self.n_layers + self.n_enc_layers
+        if self.layer_kinds:
+            mixer = {"mamba": ssm, "attention": attn}
+            blocks = sum(mixer[k] + mlp + 2 * d for k in self.layer_kinds)
+        else:
+            blocks = (self.n_layers + self.n_enc_layers) * (attn + mlp + ssm
+                                                            + 2 * d)
         cross = 0
         if self.is_encdec:
             cross = self.n_layers * (d * hd * (nh + 2 * nkv) + nh * hd * d + d)
@@ -124,7 +156,7 @@ class ModelConfig:
         front = 0
         if self.frontend_dim:
             front = self.frontend_dim * d + d  # projector
-        return emb + n_blocks * block + cross + front
+        return emb + blocks + cross + front
 
     def active_param_count(self) -> int:
         """Active params per token (MoE uses top_k of n_experts)."""

@@ -2,7 +2,7 @@
 [hf:ibm-granite/granite-3.0-1b-a400m-base].
 
 NOTE: the assignment line reads "MoE 40e top-8 — 32 experts top-8"; the config
-field (40 experts) wins, discrepancy recorded in DESIGN.md §Arch-applicability.
+field (40 experts) wins.
 """
 from repro.configs.base import ModelConfig
 

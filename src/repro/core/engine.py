@@ -185,6 +185,17 @@ def zoo_veval(eval_fns: Sequence[Callable]) -> Callable:
     return veval
 
 
+def site_vmap(fn: Callable, in_axes: tuple) -> Callable:
+    """``jax.vmap(fn, in_axes)`` over the sites; arguments beyond
+    ``in_axes`` (the frozen base that every site shares, `SwarmSession`'s
+    ``shared``) enter every site's call unmapped."""
+    def vfn(*args):
+        axes = in_axes + (None,) * (len(args) - len(in_axes))
+        return jax.vmap(fn, in_axes=axes)(*args)
+
+    return vfn
+
+
 # ---------------------------------------------------------------------------
 # pure building blocks (shared by engine, SwarmLearner, and SPMD paths)
 # ---------------------------------------------------------------------------
@@ -467,15 +478,15 @@ class SwarmEngine:
             self._vstep = train_step_fn.stacked
         else:
             self._vstep = (None if train_step_fn is None
-                           else jax.vmap(train_step_fn,
-                                         in_axes=(0, 0, 0, None)))
+                           else site_vmap(train_step_fn, (0, 0, 0, None)))
         form = self.forms[GATE]
         if form == "zoo":
             self._veval = zoo_veval(_fn_list(eval_fn, "eval_fn"))
         elif form == "stacked":
             self._veval = eval_fn.stacked
         else:
-            self._veval = None if eval_fn is None else jax.vmap(eval_fn)
+            self._veval = (None if eval_fn is None
+                           else site_vmap(eval_fn, (0, 0)))
         tracing.annotate(forms=self.forms, folded_sites=(
             cfg.n_nodes if "stacked" in self.forms.values() else 0))
         from repro.core import gossip
@@ -504,9 +515,12 @@ class SwarmEngine:
 
     # -- local training ------------------------------------------------------
 
-    def local_steps(self, params, opt_state, batches, step0, stats=None):
+    def local_steps(self, params, opt_state, batches, step0, stats=None,
+                    shared=None):
         """scan over the leading [T] time axis of vmapped local steps; the
         strategy's importance accumulation rides in the same scan.
+        ``shared``: the frozen base every site's step takes as its last
+        argument, unmapped (None: the step takes none).
 
         ``train_step_fn`` may opt into the true-Fisher hook by returning a
         4-tuple ``(params, opt_state, metrics, grads)``: the per-step grads
@@ -515,7 +529,7 @@ class SwarmEngine:
         """
         def body(carry, batch):
             p, o, st, s = carry
-            out = self._vstep(p, o, batch, s)
+            out = self._vstep(p, o, batch, s, *extra)
             if len(out) == 4:
                 p2, o2, m, grads = out
                 if st is not None:
@@ -526,6 +540,7 @@ class SwarmEngine:
                     st = self.strategy.accumulate(st, p, p2, s)
             return (p2, o2, st, s + 1), m
 
+        extra = () if shared is None else (shared,)
         init = (params, opt_state, stats, jnp.asarray(step0, jnp.int32))
         with jax.named_scope(LOCAL_STEPS):
             (p, o, st, _), metrics = jax.lax.scan(body, init, batches)
@@ -763,7 +778,7 @@ class SwarmEngine:
                                      mesh_shape=self.mesh_shape)
 
     def sync(self, params, val, active=None, stats=None, wire=None,
-             faults=None):
+             faults=None, shared=None):
         """propose → in-graph validate → gate → fused commit. Pure/traceable.
 
         ``wire``: the error-feedback wire state from `core.comms` /
@@ -782,7 +797,9 @@ class SwarmEngine:
         nobody — including the sender — commits corrupted bytes). Only the
         wire-carrying host/engine path supports injection; pass drops
         (membership masking) elsewhere. Both ``faults`` fields are runtime
-        data, so arming/disarming never retraces.
+        data, so arming/disarming never retraces. ``shared``: the frozen
+        base the gate's eval takes as its last argument (see
+        `local_steps`).
         """
         n = self.cfg.n_nodes
         a = (jnp.ones((n,), bool) if active is None
@@ -850,6 +867,7 @@ class SwarmEngine:
                 log["wire"] = new_mesh_wire
             else:
                 candidate, W, imp = self.propose(params, active, stats=stats)
+        extra = () if shared is None else (shared,)
         with jax.named_scope(GATE):
             if self.forms[GATE] == "stacked":
                 # both scorings through one loop body: the device holds one
@@ -857,11 +875,11 @@ class SwarmEngine:
                 # the vmapped one's
                 pair = jax.tree.map(lambda *x: jnp.stack(x), params,
                                     candidate)
-                local, merged = jax.lax.map(lambda p: self._veval(p, val),
-                                            pair)
+                local, merged = jax.lax.map(
+                    lambda p: self._veval(p, val, *extra), pair)
             else:
-                local = self._veval(params, val)
-                merged = self._veval(candidate, val)
+                local = self._veval(params, val, *extra)
+                merged = self._veval(candidate, val, *extra)
             metric_local = jnp.where(a, local, 1.0)
             metric_merged = jnp.where(a, merged, 0.0)
             gates = gate_decisions(metric_merged, metric_local,
@@ -908,21 +926,21 @@ class SwarmEngine:
     # -- jitted drivers ------------------------------------------------------
 
     def _round(self, params, opt_state, batches, val, active=None, step0=0,
-               stats=None, wire=None, faults=None):
+               stats=None, wire=None, faults=None, shared=None):
         """T local steps + one gated sync — a single compiled program."""
         if stats is None:
             stats = self.init_stats(params)
         params, opt_state, stats, train_metrics = self.local_steps(
-            params, opt_state, batches, step0, stats)
+            params, opt_state, batches, step0, stats, shared)
         params, log = self.sync(params, val, active, stats=stats, wire=wire,
-                                faults=faults)
+                                faults=faults, shared=shared)
         out = dict(log, train=train_metrics)
         if stats is not None:
             out["stats"] = stats
         return params, opt_state, out
 
     def _run_rounds(self, params, opt_state, batches, val, active=None,
-                    step0=0, stats=None, wire=None):
+                    step0=0, stats=None, wire=None, shared=None):
         """scan over R rounds of [R, T, N, ...] batches; no host round-trips.
 
         Fisher/gradmatch importance accumulators live inside the scan carry,
@@ -944,8 +962,10 @@ class SwarmEngine:
         if not self.cfg.overlap_sync:
             def body(carry, round_batches):
                 p, o, st, wr, s = carry
-                p, o, st, tm = self.local_steps(p, o, round_batches, s, st)
-                p, log = self.sync(p, val, active, stats=st, wire=wr)
+                p, o, st, tm = self.local_steps(p, o, round_batches, s, st,
+                                                shared)
+                p, log = self.sync(p, val, active, stats=st, wire=wr,
+                                   shared=shared)
                 wr = log.pop("wire", wr)   # wire ref rides the carry, not
                 return (p, o, st, wr, s + t), (tm, log)  # the stacked logs
 
@@ -963,8 +983,10 @@ class SwarmEngine:
             # local steps depend on the previous round's LOCAL params (plus
             # the already-available stale delta) — never on the in-flight
             # merge, so the sync below can overlap them on hardware.
-            p_loc, o, st, tm = self.local_steps(p, o, round_batches, s, st)
-            committed, log = self.sync(p_loc, val, active, stats=st, wire=wr)
+            p_loc, o, st, tm = self.local_steps(p, o, round_batches, s, st,
+                                                shared)
+            committed, log = self.sync(p_loc, val, active, stats=st, wire=wr,
+                                       shared=shared)
             wr = log.pop("wire", wr)
             delta = jax.tree.map(lambda c, l: c - l, committed, p_loc)
             p_next = jax.tree.map(lambda l, d: l + d, p_loc, pending)
@@ -982,11 +1004,12 @@ class SwarmEngine:
             logs = dict(logs, wire=wr)
         return p, o, train_metrics, logs
 
-    def _run_local(self, params, opt_state, batches, step0=0, stats=None):
+    def _run_local(self, params, opt_state, batches, step0=0, stats=None,
+                   shared=None):
         """Sync-free local training over [S, N, ...] batches. Returns
         ``(params, opt_state, metrics, stats)`` — stats is None unless
         importance accumulators were passed in (accumulation only runs when
         the caller threads them)."""
         p, o, st, metrics = self.local_steps(params, opt_state, batches,
-                                             step0, stats)
+                                             step0, stats, shared)
         return p, o, metrics, st

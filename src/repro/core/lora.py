@@ -121,6 +121,41 @@ def unflatten_payload(flat, template):
     return out
 
 
+def _without(tree, drop, prefix=""):
+    """``tree`` (nested dicts and lists) without the leaves whose path is in
+    ``drop``."""
+    if isinstance(tree, dict):
+        return {k: _without(v, drop, f"{prefix}{k}/") for k, v in tree.items()
+                if f"{prefix}{k}" not in drop}
+    if isinstance(tree, list):
+        return [_without(v, drop, f"{prefix}{i}/") for i, v in enumerate(tree)]
+    return tree
+
+
+def split_payload(params, select=None):
+    """(payload, base): the leaves ``select`` picks (default: the adapters)
+    as one flat path-keyed dict (`flatten_payload`), and ``params`` without
+    them: the frozen base that sites sharing one backbone hold once
+    (`SwarmSession`'s ``shared``)."""
+    flat = flatten_payload(params, select)
+    return flat, _without(params, set(flat))
+
+
+def attach_payload(flat, base):
+    """Inverse of :func:`split_payload`: ``base`` with the flat payload's
+    leaves written in at their paths, the containers along each path copied
+    (``base`` itself is left as it was)."""
+    def put(node, keys, leaf):
+        node = list(node) if isinstance(node, list) else dict(node)
+        k = int(keys[0]) if isinstance(node, list) else keys[0]
+        node[k] = leaf if len(keys) == 1 else put(node[k], keys[1:], leaf)
+        return node
+
+    for path, leaf in flat.items():
+        base = put(base, path.split("/"), leaf)
+    return base
+
+
 def split_adapters(params, is_leaf=None) -> Tuple[dict, dict]:
     """(adapters, base) — same treedef, non-matching leaves replaced by None.
 

@@ -137,6 +137,13 @@ class SwarmSession:
         gossip then runs over the joint axis and the per-link-class cost
         model may pick the hierarchical pod-delegate schedules.
     seed : session rng seed (defaults to ``cfg.seed``).
+    shared : a frozen pytree every site uses alike (one backbone under
+        per-site LoRA adapters), engine backend only. It is held once and
+        enters each compiled round as an argument that the sites' vmap does
+        not map over, never as a constant of the program; the train step
+        and the eval take it as their last argument, ``train_step_fn(params,
+        opt_state, batch, step, shared)`` and ``eval_fn(params, val,
+        shared)``, and the state's params are what each site trains.
     """
 
     def __init__(self, cfg: SwarmConfig, train_step_fn: Optional[Callable],
@@ -145,13 +152,22 @@ class SwarmSession:
                  backend: str = "engine", mesh=None, axis: Optional[str] = None,
                  param_specs=None, block: int = DEFAULT_BLOCK,
                  interpret: Optional[bool] = None, strategy=None,
-                 seed: Optional[int] = None, stacked: bool = False):
+                 seed: Optional[int] = None, stacked: bool = False,
+                 shared=None):
         if backend not in ("engine", "gossip", "host"):
             raise ValueError(f"unknown backend {backend!r}")
+        zoo = isinstance(train_step_fn, (list, tuple)) or isinstance(
+            eval_fn, (list, tuple))
+        if shared is not None and (backend != "engine" or zoo):
+            raise ValueError("a shared frozen base needs the engine backend "
+                             "and one train step and eval for every site: "
+                             "the gossip backend shards sites over devices, "
+                             "the host loop and zoo closures take no base")
         self.cfg = cfg
         self.backend = backend
         self.train_step_fn = train_step_fn
         self.eval_fn = eval_fn
+        self.shared = shared
         # rounds this object has dispatched, counted on the host: the ``id``
         # of its `tracing` spans (the device's ``state.round`` is never read)
         self._dispatched = 0
@@ -411,11 +427,13 @@ class SwarmSession:
     # serial and stale-by-one overlap scan bodies have exactly one home
     # (`SwarmEngine._round` / `_run_rounds` / `_run_local`).
 
-    def _round_impl(self, state: SwarmState, batches, val, faults=None):
+    def _round_impl(self, state: SwarmState, batches, val, faults=None,
+                    shared=None):
         t = jax.tree.leaves(batches)[0].shape[0]
         p, o, out = self.engine._round(state.params, state.opt_state, batches,
                                        val, state.active, state.step,
-                                       state.stats, state.wire, faults)
+                                       state.stats, state.wire, faults,
+                                       shared)
         st = out.pop("stats", None)
         wr = out.pop("wire", state.wire)
         new = SwarmState(
@@ -424,12 +442,12 @@ class SwarmSession:
             round=state.round + 1, step=state.step + t)
         return new, out
 
-    def _rounds_impl(self, state: SwarmState, batches, val):
+    def _rounds_impl(self, state: SwarmState, batches, val, shared=None):
         shape = jax.tree.leaves(batches)[0].shape
         r, t = shape[0], shape[1]
         p, o, tm, logs = self.engine._run_rounds(
             state.params, state.opt_state, batches, val, state.active,
-            state.step, state.stats, state.wire)
+            state.step, state.stats, state.wire, shared)
         st = logs.pop("stats", None)
         wr = logs.pop("wire", state.wire)
         rng = state.rng
@@ -440,10 +458,11 @@ class SwarmSession:
             rng=rng, round=state.round + r, step=state.step + r * t)
         return new, tm, logs
 
-    def _local_impl(self, state: SwarmState, batches):
+    def _local_impl(self, state: SwarmState, batches, shared=None):
         s_count = jax.tree.leaves(batches)[0].shape[0]
         p, o, tm, st = self.engine._run_local(
-            state.params, state.opt_state, batches, state.step, state.stats)
+            state.params, state.opt_state, batches, state.step, state.stats,
+            shared)
         new = dataclasses.replace(state, params=p, opt_state=o, stats=st,
                                   step=state.step + s_count)
         return new, tm
@@ -478,7 +497,7 @@ class SwarmSession:
                 return self._host_round(batches, val)
             self._state, out = self._round_jit(
                 self._state, self._on_mesh(batches, 1), self._on_mesh(val),
-                faults)
+                faults, self.shared)
             return out
 
     def run_rounds(self, batches, val):
@@ -493,7 +512,8 @@ class SwarmSession:
                 return {k: [lg[k] for lg in logs] for k in logs[0]}
             self._dispatched += jax.tree.leaves(batches)[0].shape[0]
             self._state, tm, logs = self._rounds_jit(
-                self._state, self._on_mesh(batches, 2), self._on_mesh(val))
+                self._state, self._on_mesh(batches, 2), self._on_mesh(val),
+                self.shared)
             return dict(logs, train=tm)
 
     def run_local(self, batches):
@@ -503,8 +523,8 @@ class SwarmSession:
                 for step_batches in batches:
                     self._learner.local_steps(step_batches)
                 return None
-            self._state, tm = self._local_jit(self._state,
-                                              self._on_mesh(batches, 1))
+            self._state, tm = self._local_jit(
+                self._state, self._on_mesh(batches, 1), self.shared)
             return tm
 
     def _host_round(self, batches, val):

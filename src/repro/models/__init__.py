@@ -22,7 +22,8 @@ from repro.models.encdec import (
 )
 from repro.models.layers import softmax_xent
 from repro.models.transformer import (
-    forward_lm, init_lm, make_lm_cache, project_frontend,
+    forward_lm, init_lm, lm_accuracy, lm_hidden, lm_xent, make_lm_cache,
+    project_frontend,
 )
 
 
@@ -34,13 +35,19 @@ class Model:
     decode: Callable[..., Any]            # (params, tokens, caches, cache_pos)
     init_cache: Callable[..., Any]        # (batch_size, max_len) -> caches
     prefill: Optional[Callable[..., Any]] = None
+    # (params, batch) -> share of tokens whose largest logit is the label
+    accuracy: Optional[Callable[..., Any]] = None
 
 
 def _lm_model(cfg: ModelConfig) -> Model:
     def loss_fn(params, batch, remat=True):
-        logits, aux, _ = forward_lm(params, cfg, batch["tokens"], remat=remat)
-        xent = softmax_xent(logits, batch["labels"], batch.get("mask"))
+        x, aux, _ = lm_hidden(params, cfg, batch["tokens"], remat=remat)
+        xent = lm_xent(params, cfg, x, batch["labels"], batch.get("mask"))
         return xent + aux, {"xent": xent, "aux": aux}
+
+    def accuracy(params, batch):
+        x, _, _ = lm_hidden(params, cfg, batch["tokens"])
+        return lm_accuracy(params, cfg, x, batch["labels"], batch.get("mask"))
 
     def prefill(params, batch, caches):
         logits, _, caches = forward_lm(
@@ -54,7 +61,7 @@ def _lm_model(cfg: ModelConfig) -> Model:
         return logits, caches
 
     return Model(cfg, lambda key: init_lm(key, cfg), loss_fn, decode,
-                 lambda b, m: make_lm_cache(cfg, b, m), prefill)
+                 lambda b, m: make_lm_cache(cfg, b, m), prefill, accuracy)
 
 
 def _vlm_model(cfg: ModelConfig) -> Model:

@@ -3,6 +3,10 @@
 Used by every attention-bearing family (dense, moe, hybrid, vlm, audio).
 Pure jnp by default (this is the path the multi-pod dry-run lowers); the
 Pallas flash kernel in ``repro.kernels`` is an opt-in drop-in for TPU.
+``cfg.use_rope`` off leaves positions out of q and k (Granite's "nope"),
+``cfg.attention_multiplier`` replaces 1/sqrt(head_dim) as the softmax
+scale, and ``cfg.attn_chunk`` computes a long sequence's scores that many
+query rows at a time, each chunk recomputed in the backward pass.
 """
 from __future__ import annotations
 
@@ -37,13 +41,17 @@ def make_cache(cfg: ModelConfig, batch: int, max_len: int, dtype, *, n_kv_heads=
     return {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype)}
 
 
-def _gqa_scores(q, k):
-    """q [B,S,nh,hd], k [B,T,nkv,hd] -> scores [B,nkv,g,S,T] (g = nh // nkv)."""
+def _gqa_scores(q, k, scale=0.0):
+    """q [B,S,nh,hd], k [B,T,nkv,hd] -> scores [B,nkv,g,S,T] (g = nh // nkv),
+    times ``scale`` (0: divided by sqrt(hd))."""
     b, s, nh, hd = q.shape
     nkv = k.shape[2]
     g = nh // nkv
     qg = q.reshape(b, s, nkv, g, hd)
-    return jnp.einsum("bskgh,btkh->bkgst", qg, k) / jnp.sqrt(hd).astype(q.dtype)
+    qk = jnp.einsum("bskgh,btkh->bkgst", qg, k)
+    if scale:
+        return qk * jnp.asarray(scale, q.dtype)
+    return qk / jnp.sqrt(hd).astype(q.dtype)
 
 
 def _gqa_out(probs, v):
@@ -80,7 +88,7 @@ def attention(
     v = linear(p["v"], src).reshape(b, src.shape[1], nkv, hd)
 
     is_cross = kv_x is not None
-    if not is_cross:
+    if not is_cross and cfg.use_rope:
         q = apply_rope(q, positions, cfg.rope_theta)
         kp = positions if kv_positions is None else kv_positions
         k = apply_rope(k, kp, cfg.rope_theta)
@@ -127,23 +135,40 @@ def attention(
         if key_positions.ndim == 1:
             key_positions = key_positions[None, :]
 
-    scores = _gqa_scores(q, k)  # [B,nkv,g,S,T]
-    if head_parallel:
-        scores = logical_shard(scores, "batch", "kv_heads", None, None, None)
-    elif s > 1:
-        scores = logical_shard(scores, "batch", None, None, "attn_seq", None)
-    qpos = positions[:, None, None, :, None]          # [B,1,1,S,1]
-    kpos = key_positions[:, None, None, None, :]      # [B,1,1,1,T]
-    if causal and not is_cross:
-        # `window` may be a traced per-layer scalar (scan-over-layers); 0 = full
-        w = jnp.asarray(window, jnp.int32)
-        w_eff = jnp.where(w > 0, w, jnp.int32(2**30))
-        mask = (kpos <= qpos) & (kpos > qpos - w_eff)
+    def attend(q, positions):
+        scores = _gqa_scores(q, k, cfg.attention_multiplier)  # [B,nkv,g,S,T]
+        s = q.shape[1]
+        if head_parallel:
+            scores = logical_shard(scores, "batch", "kv_heads", None, None,
+                                   None)
+        elif s > 1:
+            scores = logical_shard(scores, "batch", None, None, "attn_seq",
+                                   None)
+        qpos = positions[:, None, None, :, None]          # [B,1,1,S,1]
+        kpos = key_positions[:, None, None, None, :]      # [B,1,1,1,T]
+        if causal and not is_cross:
+            # `window` may be a traced per-layer scalar (scan-over-layers);
+            # 0 = full
+            w = jnp.asarray(window, jnp.int32)
+            w_eff = jnp.where(w > 0, w, jnp.int32(2**30))
+            mask = (kpos <= qpos) & (kpos > qpos - w_eff)
+        else:
+            mask = jnp.ones(scores.shape[-2:], bool)[None, None, None, :, :]
+        scores = jnp.where(mask, scores.astype(jnp.float32), NEG_INF)
+        probs = jax.nn.softmax(scores, axis=-1).astype(x.dtype)
+        return _gqa_out(probs, v)  # [B,S,nh,hd]
+
+    c = cfg.attn_chunk
+    if cache is None and c and s > c and s % c == 0:
+        def rows(t):  # [B, S, ...] -> [S / c, B, c, ...]
+            return jnp.moveaxis(t.reshape(b, s // c, c, *t.shape[2:]), 1, 0)
+
+        pos = jnp.broadcast_to(positions, (b, s))
+        out = jax.lax.map(lambda a: jax.checkpoint(attend)(*a),
+                          (rows(q), rows(pos)))
+        out = jnp.moveaxis(out, 0, 1).reshape(b, s, nh, hd)
     else:
-        mask = jnp.ones(scores.shape[-2:], bool)[None, None, None, :, :]
-    scores = jnp.where(mask, scores.astype(jnp.float32), NEG_INF)
-    probs = jax.nn.softmax(scores, axis=-1).astype(x.dtype)
-    out = _gqa_out(probs, v)  # [B,S,nh,hd]
+        out = attend(q, positions)
     if head_parallel:
         out = logical_shard(out, "batch", "seq", "heads", None)
     elif s > 1:

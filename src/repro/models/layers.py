@@ -99,6 +99,11 @@ def apply_rope(x, positions, theta: float):
 def init_mlp(key, cfg: ModelConfig, d_ff: Optional[int] = None):
     d, f = cfg.d_model, (d_ff or cfg.d_ff)
     ks = jax.random.split(key, 3)
+    if cfg.activation == "swiglu_fused":
+        return {
+            "gate_up": init_linear(ks[0], d, 2 * f, cfg),
+            "down": init_linear(ks[1], f, d, cfg),
+        }
     if cfg.activation == "swiglu":
         return {
             "gate": init_linear(ks[0], d, f, cfg),
@@ -112,7 +117,10 @@ def init_mlp(key, cfg: ModelConfig, d_ff: Optional[int] = None):
 
 
 def mlp(p, x, cfg: ModelConfig):
-    if cfg.activation == "swiglu":
+    if cfg.activation == "swiglu_fused":
+        gate, up = jnp.split(linear(p["gate_up"], x), 2, axis=-1)
+        h = jax.nn.silu(gate) * up
+    elif cfg.activation == "swiglu":
         h = jax.nn.silu(linear(p["gate"], x)) * linear(p["up"], x)
     elif cfg.activation == "sq_relu":  # nemotron-4: squared ReLU
         h = jnp.square(jax.nn.relu(linear(p["up"], x)))

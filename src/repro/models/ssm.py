@@ -191,7 +191,8 @@ def ssm_block(p, x, cfg: ModelConfig, *, state=None):
         if pad:
             padded = lambda t: jnp.pad(t, [(0, 0), (0, pad)] + [(0, 0)] * (t.ndim - 2))
             xh, bm, cm, dtv = padded(xh), padded(bm), padded(cm), padded(dtv)
-        y, final = ssd_chunked(xh, dtv, p["A_log"], bm, cm, chunk)
+        with jax.named_scope("lm.ssd"):
+            y, final = ssd_chunked(xh, dtv, p["A_log"], bm, cm, chunk)
         y = y[:, :s]
         new_state = {"ssd": final, "conv": new_conv} if state is not None else None
 

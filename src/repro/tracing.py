@@ -14,11 +14,14 @@ round's local step and gate and the sites they fold
 (`core.engine.SwarmEngine`, inside ``session.build``).
 
 Device stages: `core.engine` wraps the round's four stages in
-`jax.named_scope` under the names in `ROUND_SCOPES`. XLA keeps a scope in
-each instruction's ``metadata={op_name="…/swarm.gate/…"}``;
-`scope_of_ops` reads it back from a compiled module's text
-(``Compiled.as_text()``), so the ops of a device trace, which are named
-by their HLO names, can be summed per stage.
+`jax.named_scope` under the names in `ROUND_SCOPES`; a language model's
+layers carry the names in `LM_SCOPES` inside them (`models.transformer`).
+XLA keeps a scope in each instruction's
+``metadata={op_name="…/swarm.gate/…"}``, in a backward pass inside the
+transform's name (``transpose(jvp(lm.mlp))``); `scope_of_ops` reads it
+back from a compiled module's text (``Compiled.as_text()``), so the ops
+of a device trace, which are named by their HLO names, can be summed per
+stage.
 """
 from __future__ import annotations
 
@@ -30,6 +33,9 @@ from typing import Optional
 
 ROUND_SCOPES = ("swarm.local_steps", "swarm.propose", "swarm.gate",
                 "swarm.commit")
+# a language model's parts: the Mamba-2 mixer (its scan under lm.ssd),
+# attention, the MLP, and the final norm, logits and loss
+LM_SCOPES = ("lm.mixer", "lm.ssd", "lm.attn", "lm.mlp", "lm.head")
 
 
 @dataclass
@@ -136,18 +142,18 @@ drain = _recorder.drain
 
 _INSTR = re.compile(r"^\s*(?:ROOT\s+)?%?([\w.\-]+) = .*?"
                     r"metadata=\{[^}]*?op_name=\"([^\"]*)\"", re.M)
-_SCOPE = re.compile(r"(?:^|/)(" + "|".join(map(re.escape, ROUND_SCOPES))
-                    + r")(?=/|$)")
 
 
-def scope_of_ops(hlo_text: str) -> dict[str, str]:
-    """``{HLO instruction name: round scope}`` for every instruction of a
-    compiled module whose ``op_name`` lies under one of `ROUND_SCOPES`
-    (the innermost, should they nest). Instructions of no scope, such as
+def scope_of_ops(hlo_text: str, scopes=ROUND_SCOPES) -> dict[str, str]:
+    """``{HLO instruction name: scope}`` for every instruction of a
+    compiled module whose ``op_name`` lies under one of ``scopes`` (the
+    innermost, should they nest). Instructions of no scope, such as
     copies XLA adds, are left out."""
+    named = re.compile(r"(?:^|[/(])(" + "|".join(map(re.escape, scopes))
+                       + r")(?=[/)]|$)")
     out = {}
     for name, op_name in _INSTR.findall(hlo_text):
-        scopes = _SCOPE.findall(op_name)
-        if scopes:
-            out[name] = scopes[-1]
+        found = named.findall(op_name)
+        if found:
+            out[name] = found[-1]
     return out

@@ -89,7 +89,8 @@ def test_smoke_decode_step(arch):
         tok = jnp.argmax(logits[:, :, :cfg.vocab_size], -1).astype(jnp.int32)
 
 
-@pytest.mark.parametrize("arch", ["mamba2-370m", "hymba-1.5b"])
+@pytest.mark.parametrize("arch", ["mamba2-370m", "hymba-1.5b",
+                                  "granite-4.0-h-micro"])
 def test_decode_matches_forward(arch):
     """Step-by-step decode == teacher-forced forward (recurrent families)."""
     cfg = smoke_variant(get_config(arch)).replace(ssm_chunk=8)
