@@ -12,6 +12,7 @@ Hardware constants: TPU v5e — 197 TFLOP/s bf16, 819 GB/s HBM, ~50 GB/s/link IC
 """
 from __future__ import annotations
 
+import json
 import re
 from dataclasses import dataclass, field
 from typing import Dict
@@ -144,6 +145,35 @@ def tile_padding(hlo_text: str):
                 stored += s
                 logical += l
     return stored, logical
+
+
+_CONFIGURED_RE = re.compile(
+    r"^\s*(?:ROOT\s+)?%?([\w.\-]+) = (.*?)backend_config=", re.M)
+_OP_NAME_RE = re.compile(r'op_name="([^"]*)"')
+
+
+def estimated_cycles(hlo_text: str) -> list:
+    """The TPU compiler's estimate for each instruction of a compiled
+    module's text (``Compiled.as_text()``) that carries one: dicts of its
+    ``name``, ``cycles``, ``op_name`` and, for a convolution, the
+    ``emitter`` the compiler picked; the most cycles first. These are
+    compiler estimates, not times, and a loop's body counts once."""
+    decode = json.JSONDecoder().raw_decode
+    out = []
+    for m in _CONFIGURED_RE.finditer(hlo_text):
+        try:
+            config, _ = decode(hlo_text, m.end())
+        except json.JSONDecodeError:
+            continue
+        cycles = config.get("window_config", {}).get("estimated_cycles")
+        if cycles is None:
+            continue
+        op_name = _OP_NAME_RE.search(m.group(2))
+        out.append({"name": m.group(1), "cycles": int(cycles),
+                    "op_name": op_name.group(1) if op_name else "",
+                    "emitter": config.get("convolution_algorithm_config",
+                                          {}).get("emitter")})
+    return sorted(out, key=lambda i: -i["cycles"])
 
 
 # --- per-link-class split (two-level ("pod", "node") meshes) ----------------

@@ -129,6 +129,12 @@ def bce_loss(logits, labels_onehot):
 # code, forward and backward, is about twice the vmapped one's, the device
 # holds that code in its memory, and the bytes folding saves shrink with
 # the activations.
+#
+# The stem runs as a space-to-depth convolution (`stem_sites`) in a
+# forward alone, and strided in a training step: there XLA already gives
+# the strided form's kernel a fast layout (its weight gradient fixes it),
+# and at 224 px it estimates the space-to-depth form's transpose and two
+# convolutions at more cycles than the strided form's.
 
 TRAIN_FOLD_SHARE = 64
 
@@ -154,6 +160,50 @@ def conv2d_sites(w, x, stride=1, padding="SAME"):
     block_diag = jnp.where(site_in[:, None] == site_out[None, :],
                            jnp.repeat(rows, n, axis=-1), 0.0)
     return conv2d(block_diag, x, stride, padding)
+
+
+def stem_sites(w, images):
+    """The stem's folded convolution, ``conv2d_sites(w, fold_sites(images),
+    stride=2)`` (SAME), as a stride-1 convolution over 2x2 pixel blocks.
+
+    ``w [N, k, k, cin, cout]``, ``images [N, B, H, W, cin]`` ->
+    ``[B, ceil(H/2), ceil(W/2), cout·N]``. One transpose folds each 2x2
+    block of pixels into the channels beside the sites, ``[B, H/2, W/2,
+    4·cin·N]`` (the site the minor index), and the kernel, zero-padded to
+    an even size, becomes a block kernel of half the size; the padding
+    taps add exact zeros. For the paper's 7x7 stem over 12 folded
+    features, XLA lays the strided convolution's kernel out with those
+    features in the lanes and picks a slow emitter: at the gate's shape,
+    4 sites x 38 rows x 224 px on a v5e, XLA estimates that convolution at
+    ten times the cycles of this one, and at nearly four times this one
+    and its transpose together."""
+    n, b, h, wd, cin = images.shape
+    k, cout = w.shape[1], w.shape[-1]
+    kb = (k + 1) // 2
+
+    def pads(size):
+        # SAME's padding for a stride-2 window, then the zero-tap row:
+        # pixels where they pair a pixel with padding, the rest in blocks
+        out = (size + 1) // 2
+        lo = max(2 * (out - 1) + k - size, 0) // 2
+        hi = 2 * (out + kb - 1) - size - lo
+        pix = (lo % 2, (size + lo) % 2)
+        return pix, ((lo - pix[0]) // 2, (hi - pix[1]) // 2)
+
+    (pix_h, blk_h), (pix_w, blk_w) = pads(h), pads(wd)
+    x = jnp.pad(images, ((0, 0), (0, 0), pix_h, pix_w, (0, 0)))
+    hb, wb = x.shape[2] // 2, x.shape[3] // 2
+    x = x.reshape(n, b, hb, 2, wb, 2, cin).transpose(1, 2, 4, 3, 5, 6, 0)
+    x = x.reshape(b, hb, wb, 4 * cin * n)
+    w = jnp.pad(w, ((0, 0), (0, 2 * kb - k), (0, 2 * kb - k), (0, 0), (0, 0)))
+    w = w.reshape(n, kb, 2, kb, 2, cin, cout).transpose(1, 3, 2, 4, 5, 0, 6)
+    # block-diagonal across sites by a product with the identity: from a
+    # select, as in `conv2d_sites`, XLA lays this kernel out with its
+    # input features in the lanes, and estimates the convolution at twice
+    # the cycles
+    w = w[..., None] * jnp.eye(n, dtype=w.dtype)[:, None, :]
+    return conv2d(w.reshape(kb, kb, 4 * cin * n, cout * n), x, 1,
+                  (blk_h, blk_w))
 
 
 def _bn_sites(p):
@@ -189,7 +239,11 @@ def forward_cnn_sites(params, images, fold_share=None):
     the encoder modules whose input keeps no more than 1/fold_share of the
     image's pixels run per site, vmapped, instead."""
     n, pixels = images.shape[0], images.shape[2] * images.shape[3]
-    x = conv2d_sites(params["stem"]["w"], fold_sites(images), stride=2)
+    with jax.named_scope("cnn.stem"):
+        if fold_share is None:
+            x = stem_sites(params["stem"]["w"], images)
+        else:
+            x = conv2d_sites(params["stem"]["w"], fold_sites(images), stride=2)
     x = jax.nn.relu(batchnorm(_bn_sites(params["stem"]["bn"]), x))
     x = jax.lax.reduce_window(x, -jnp.inf, jax.lax.max, (1, 3, 3, 1),
                               (1, 2, 2, 1), "SAME")

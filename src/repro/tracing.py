@@ -15,7 +15,8 @@ round's local step and gate and the sites they fold
 
 Device stages: `core.engine` wraps the round's four stages in
 `jax.named_scope` under the names in `ROUND_SCOPES`; a language model's
-layers carry the names in `LM_SCOPES` inside them (`models.transformer`).
+layers carry the names in `LM_SCOPES` inside them (`models.transformer`),
+and the site-folded DenseNet its stem convolution in `CNN_SCOPES`.
 XLA keeps a scope in each instruction's
 ``metadata={op_name="…/swarm.gate/…"}``, in a backward pass inside the
 transform's name (``transpose(jvp(lm.mlp))``); `scope_of_ops` reads it
@@ -36,6 +37,8 @@ ROUND_SCOPES = ("swarm.local_steps", "swarm.propose", "swarm.gate",
 # a language model's parts: the Mamba-2 mixer (its scan under lm.ssd),
 # attention, the MLP, and the final norm, logits and loss
 LM_SCOPES = ("lm.mixer", "lm.ssd", "lm.attn", "lm.mlp", "lm.head")
+# the DenseNet's parts (`models.cnn.forward_cnn_sites`): its stem convolution
+CNN_SCOPES = ("cnn.stem",)
 
 
 @dataclass

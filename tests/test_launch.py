@@ -53,6 +53,23 @@ ENTRY %main (x: f32[4,32]) -> f32[4,160] {
     assert stored == 4 * (8 * 128 * 4) + 160 * 128 * 4
 
 
+def test_estimated_cycles_reads_the_compilers_estimates_most_first():
+    txt = "\n".join([
+        '  %copy.3 = bf16[8,128]{1,0} copy(%p), metadata={op_name="jit(f)/'
+        'cnn.stem/transpose"}, backend_config={"window_config":{'
+        '"estimated_cycles":"120"}}',
+        '  ROOT %fusion.2 = f32[8,128]{1,0} fusion(%copy.3, %w), kind=kOutput,'
+        ' calls=%c, backend_config={"window_config":{"estimated_cycles":'
+        '"4096"},"convolution_algorithm_config":{"emitter":"EmitX"}}',
+        '  %add.1 = f32[8]{0} add(%a, %b), backend_config={"flag_configs":[]}',
+    ])
+    assert hlo_stats.estimated_cycles(txt) == [
+        {"name": "fusion.2", "cycles": 4096, "op_name": "",
+         "emitter": "EmitX"},
+        {"name": "copy.3", "cycles": 120,
+         "op_name": "jit(f)/cnn.stem/transpose", "emitter": None}]
+
+
 def test_parser_tuple_result_and_async():
     txt = """
       %all-reduce = (f32[768,2304]{1,0}, f32[2304]{0}, /*index=5*/f32[10,14]{1,0}) all-reduce(%a, %b, %c)

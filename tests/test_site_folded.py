@@ -22,7 +22,7 @@ from repro.core.session import SwarmSession
 from repro.experiments import histo
 from repro.models import cnn
 from repro.models.cnn import (bce_loss, conv2d, conv2d_sites, forward_cnn,
-                              forward_cnn_sites)
+                              forward_cnn_sites, stem_sites)
 from repro.optim import adamw_init
 
 RTOL = 1e-4
@@ -43,8 +43,8 @@ ROUNDING_ONLY = ("['stem']['bn']['scale']", "['head']['fc1']['b']",
                  "['head']['fc2']['b']")
 
 
-def _images(n, rows, key=1):
-    return jax.random.normal(jax.random.key(key), (n, rows, 24, 24, 3))
+def _images(n, rows, key=1, size=24):
+    return jax.random.normal(jax.random.key(key), (n, rows, size, size, 3))
 
 
 def _close(got, want, rtol=RTOL, where=None):
@@ -94,26 +94,51 @@ def test_conv_sites_is_the_conv_of_each_site(n, cin, cout):
     _close(grads[0], grads[1])
 
 
-@pytest.mark.parametrize("fold_share", [None, cnn.TRAIN_FOLD_SHARE],
-                         ids=["all", "train"])
-@pytest.mark.parametrize("n", [1, 4])
-def test_folded_forward_matches_vmap(n, fold_share):
+@pytest.mark.parametrize("n", [1, 2, 4])
+@pytest.mark.parametrize("size", [16, 17, 33])
+def test_space_to_depth_stem_is_the_strided_folded_conv(size, n):
+    """Width one more than height, so each size meets the other parity:
+    SAME pads an odd size evenly and an even one a pixel more below."""
+    w = jax.random.normal(jax.random.key(0), (n, 7, 7, 3, 8))
+    x = jax.random.normal(jax.random.key(1), (n, 3, size, size + 1, 3))
+    with jax.default_matmul_precision("highest"):
+        got = jax.jit(stem_sites)(w, x)
+        want = jax.jit(lambda w, x: conv2d_sites(w, cnn.fold_sites(x),
+                                                 stride=2))(w, x)
+    _close(got, want, rtol=1e-5)
+
+
+ALL, TRAIN = None, cnn.TRAIN_FOLD_SHARE
+
+
+@pytest.mark.parametrize("n,fold_share,size", [
+    (1, ALL, 24), (1, TRAIN, 24), (4, ALL, 24), (4, TRAIN, 24),
+    (4, ALL, 17), (4, TRAIN, 17)],
+    ids=["1-all", "1-train", "4-all", "4-train", "4-all-17px",
+         "4-train-17px"])
+def test_folded_forward_matches_vmap(n, fold_share, size):
     params = _stacked_params(n)
-    x = _images(n, 6)
+    x = _images(n, 6, size=size)
     folded = jax.jit(lambda p, x: forward_cnn_sites(p, x, fold_share))
     _close(folded(params, x), jax.jit(jax.vmap(forward_cnn))(params, x))
 
 
-def _conv_groups(images, fold_share, **widths):
-    """``feature_group_count`` of each convolution of the folded forward:
-    1 for a folded convolution, N for a vmapped per-site one."""
+def _convs(images, fold_share, **widths):
+    """The equations of the folded forward's convolutions, in order."""
     params = jax.eval_shape(lambda: jax.vmap(
         lambda k: cnn.init_cnn(k, None, **widths))(
             jax.random.split(jax.random.key(0), images.shape[0])))
     jaxpr = jax.make_jaxpr(
         lambda p, x: forward_cnn_sites(p, x, fold_share))(params, images)
-    return [e.params["feature_group_count"] for e in jaxpr.eqns
+    return [e for e in jaxpr.eqns
             if e.primitive.name == "conv_general_dilated"]
+
+
+def _conv_groups(images, fold_share, **widths):
+    """``feature_group_count`` of each convolution of the folded forward:
+    1 for a folded convolution, N for a vmapped per-site one."""
+    return [c.params["feature_group_count"]
+            for c in _convs(images, fold_share, **widths)]
 
 
 @pytest.mark.parametrize("size,widths,convs", [
@@ -127,6 +152,21 @@ def test_a_train_step_folds_the_stem_and_the_large_blocks(size, widths,
     assert _conv_groups(images, None, **widths) == [1] * (folded + per_site)
     assert (_conv_groups(images, cnn.TRAIN_FOLD_SHARE, **widths)
             == [1] * folded + [4] * per_site)
+
+
+@pytest.mark.parametrize("fold_share,window,stride", [
+    (ALL, 4, 1), (TRAIN, 7, 2)], ids=["gate", "train"])
+def test_the_gate_takes_the_space_to_depth_stem_and_a_step_the_strided(
+        fold_share, window, stride):
+    """A forward alone (the gate) runs the stem as `stem_sites`, a 4x4
+    convolution of 2x2 pixel blocks; a training step keeps the 7x7
+    stride-2 one, as XLA estimates the space-to-depth form's transpose
+    and convolutions, its weight gradient included, at more cycles than
+    the strided form's at the step's shape."""
+    images = jax.ShapeDtypeStruct((4, 2, 224, 224, 3), jnp.float32)
+    stem = _convs(images, fold_share)[0]
+    assert stem.params["window_strides"] == (stride, stride)
+    assert stem.invars[1].aval.shape[:2] == (window, window)
 
 
 @pytest.mark.parametrize("n", [1, 4])
@@ -297,3 +337,15 @@ def test_a_folded_session_round_is_the_vmapped_one(on_tiled_lanes):
         if not name.endswith(ROUNDING_ONLY):
             gap = np.linalg.norm(g - w) / np.linalg.norm(w - w0)
             assert gap < 0.05, (name, gap)
+
+
+def test_the_stem_scope_names_ops_of_the_gate_and_of_the_step(
+        on_tiled_lanes):
+    session = _session(4)
+    xs, ys, val = _round_inputs(4, rounds=1)
+    text = session._round_jit.lower(session._state, (xs[0], ys[0]), val,
+                                    None).compile().as_text()
+    stem = tracing.scope_of_ops(text, tracing.CNN_SCOPES)
+    stage = tracing.scope_of_ops(text)
+    # (a reducer's instructions carry the scope outside any stage)
+    assert {GATE, LOCAL_STEPS} <= {stage.get(n) for n in stem}

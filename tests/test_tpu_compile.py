@@ -21,7 +21,8 @@ from jax.sharding import SingleDeviceSharding
 from repro.configs.paper_histo import PAPER_FULL
 from repro.kernels.fused_merge import fused_merge_tree, fused_quant_merge_tree
 from repro.kernels.lora_matmul import lora_matmul
-from repro.models.cnn import init_cnn
+from repro.launch.hlo_stats import estimated_cycles
+from repro.models.cnn import conv2d_sites, fold_sites, init_cnn, stem_sites
 
 N = 4
 
@@ -140,3 +141,27 @@ def test_auto_block_tiles_fit_vmem_at_64_sites(one_chip):
         return plain, quant
 
     _assert_mosaic(jax.jit(commits).lower(x, W, gates).compile(), 2)
+
+
+def test_the_gates_stem_convolution_is_estimated_cheap_for_v5e(one_chip):
+    """The gate's stem at its shape (4 sites x 38 validation rows x 224 px,
+    both scorings in one loop as `SwarmEngine.sync` runs them): XLA picks a
+    slow emitter for the 7x7 stride-2 folded convolution, and estimates
+    the space-to-depth form's (`stem_sites`) at under a third of its
+    cycles. Relative, so that it holds across compiler versions."""
+    w = _sds((2, N, 7, 7, 3, PAPER_FULL.stem), jnp.float32, one_chip)
+    x = _sds((N, 38, PAPER_FULL.image_size, PAPER_FULL.image_size, 3),
+             jnp.float32, one_chip)
+
+    def strided(w, x):
+        return conv2d_sites(w, fold_sites(x), stride=2)
+
+    cycles = []
+    for stem in (strided, stem_sites):
+        compiled = jax.jit(lambda w, x: jax.lax.map(
+            lambda w: stem(w, x), w)).lower(w, x).compile()
+        convs = [i for i in estimated_cycles(compiled.as_text())
+                 if i["emitter"]]
+        assert len(convs) == 1, convs
+        cycles.append(convs[0]["cycles"])
+    assert 3 * cycles[1] < cycles[0], cycles

@@ -1,6 +1,6 @@
 #!/usr/bin/env python3
-"""Where an LM swarm cell's round goes: device time per traced round under
-each of the language model's scopes and each of the round's stages.
+"""Where a swarm cell's round goes: device time per traced round under
+each of the model's own scopes and each of the round's stages.
 
     python3 tools/lm_stages.py --workload <cell> --seed <n> --seconds <s>
 
@@ -13,13 +13,17 @@ one JSON line:
   milliseconds per traced round under `repro.tracing.LM_SCOPES` (each op
   under its innermost scope: the Mamba-2 scan under ``lm.ssd`` is not in
   ``mixer_ms``), forward and backward alike, and ``lm_other_ms`` under
-  none of them (embedding, AdamW, proposal, commit);
+  none of them (embedding, AdamW, proposal, commit), in an LM cell;
+- ``stem_ms``: the same under `repro.tracing.CNN_SCOPES`, the DenseNet's
+  stem convolution in the gate and the local steps together, in a
+  DenseNet cell;
 - ``local_step_ms``, ``propose_ms``, ``gate_ms``, ``commit_ms``,
   ``other_ms``: the same split by the round's stages
   (`swarmbench/stages.py`);
 - ``busy_ms``, the device's busy time per round, and the program's
-  ``session.build`` attributes (the model, its layer kinds, the frozen
-  base's bytes, the adapters a site).
+  ``session.build`` attributes (an LM's model, its layer kinds, the
+  frozen base's bytes, the adapters a site; the forms of the local step
+  and the gate).
 
 Op names map to scopes through the compiled round's text
 (`repro.tracing.scope_of_ops`), which is read after the window from the
@@ -45,6 +49,7 @@ from swarmbench import run, stages, trace as tr  # noqa: E402
 LM_METRIC = dict(zip(tracing.LM_SCOPES + (stages.OTHER,),
                      ("mixer_ms", "ssd_ms", "attn_ms", "mlp_ms", "head_ms",
                       "lm_other_ms")))
+CNN_METRIC = {"cnn.stem": "stem_ms"}
 
 
 def measure(cell_spec, config, traffic, *, seed, seconds, clock,
@@ -68,10 +73,13 @@ def measure(cell_spec, config, traffic, *, seed, seconds, clock,
             session.shared).compile().as_text()
         per_round = 1e3 / w["traced_rounds"]
         for scopes, names in ((tracing.LM_SCOPES, LM_METRIC),
+                              (tracing.CNN_SCOPES, CNN_METRIC),
                               (tracing.ROUND_SCOPES, stages.METRIC)):
-            secs = stages.scope_seconds(
-                traced, tracing.scope_of_ops(text, scopes), lo, hi)
-            out.update({names[k]: v * per_round for k, v in secs.items()})
+            scope_of = tracing.scope_of_ops(text, scopes)
+            if scope_of:
+                secs = stages.scope_seconds(traced, scope_of, lo, hi)
+                out.update({names[k]: v * per_round
+                            for k, v in secs.items() if k in names})
         out["busy_ms"] = tr.summarize(traced)["busy_s"] * per_round
     cell.free()
     return out

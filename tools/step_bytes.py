@@ -4,7 +4,7 @@ vmapped against site-folded, compiled for a described TPU v5e (nothing
 runs; no chip).
 
     JAX_PLATFORMS=cpu PYTHONPATH=src python tools/step_bytes.py \
-        [--only step.vmap,step.folded,forward.vmap,forward.folded]
+        [--only step.vmap,step.folded,forward.vmap,forward.folded] [--top K]
 
 At the benchmark's shapes (4 sites x batch 32 x 224 px at the paper's
 widths; the gate at 38 validation rows a site) it compiles the train step
@@ -18,8 +18,17 @@ program's temporary and code bytes (`Compiled.memory_analysis`; the
 device holds the code in its memory, so a round's code counts in the
 benchmark's ``peak_device_bytes``), and the bytes of the operands and
 results of its unfused instructions, as stored in their tiles and as
-logical (`launch.hlo_stats.tile_padding`). These are compiler counts, not
-times. A step or round compile takes one to three minutes on a CPU.
+logical (`launch.hlo_stats.tile_padding`). With ``--top K``, each
+program's line is followed by one line for each of its K instructions
+that the compiler estimates at the most cycles
+(`launch.hlo_stats.estimated_cycles`): its name, the cycles, its round
+stage and model part (`repro.tracing` scopes, from its ``op_name``; a
+backward pass's under the forward's) and, for a convolution, the emitter
+the compiler picked. A compile for a described v5e names its fusions as
+the chip's trace does, so the instructions of a ledger line's
+``breakdown.device_ops`` can be looked up here. These are compiler counts
+and estimates, not times (a loop's body counts once). A step or round
+compile takes one to three minutes on a CPU.
 """
 from __future__ import annotations
 
@@ -33,13 +42,14 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import SingleDeviceSharding
 
+from repro import tracing
 from repro.configs.base import SwarmConfig
 from repro.configs.paper_histo import PAPER_FULL
 from repro.core import engine
 from repro.core.session import SwarmSession
 from repro.experiments.histo import (HistoExperimentConfig, _init_params,
                                      _make_model_fns, _swarm_session)
-from repro.launch.hlo_stats import tile_padding
+from repro.launch.hlo_stats import estimated_cycles, tile_padding
 from repro.models.cnn import forward_cnn, forward_cnn_sites
 from repro.optim import adamw_init
 
@@ -120,7 +130,11 @@ def main(argv=None):
     ap = argparse.ArgumentParser(description=__doc__.split("\n")[0])
     ap.add_argument("--only", default="step.vmap,step.folded,forward.vmap,"
                     "forward.folded", help="comma-separated program names")
-    names = [n for n in ap.parse_args(argv).only.split(",") if n]
+    ap.add_argument("--top", type=int, default=0, metavar="K",
+                    help="also print each program's K instructions with "
+                    "the most estimated cycles")
+    opts = ap.parse_args(argv)
+    names = [n for n in opts.only.split(",") if n]
     os.environ.setdefault("TPU_LOG_DIR", "disabled")
     jax.config.update("jax_enable_compilation_cache", False)
     # the programs are compiled for a TPU: the engine takes the forms it
@@ -141,7 +155,8 @@ def main(argv=None):
         cost = compiled.cost_analysis()
         cost = cost[0] if isinstance(cost, list) else cost
         mem = compiled.memory_analysis()
-        stored, logical = tile_padding(compiled.as_text())
+        text = compiled.as_text()
+        stored, logical = tile_padding(text)
         print(json.dumps({"program": name,
                           "bytes_accessed": cost["bytes accessed"],
                           "flops": cost["flops"],
@@ -149,6 +164,16 @@ def main(argv=None):
                           "code_bytes": mem.generated_code_size_in_bytes,
                           "unfused_bytes_stored": stored,
                           "unfused_bytes_logical": logical}), flush=True)
+        if opts.top:
+            stage = tracing.scope_of_ops(text)
+            part = tracing.scope_of_ops(
+                text, tracing.CNN_SCOPES + tracing.LM_SCOPES)
+            for i in estimated_cycles(text)[:opts.top]:
+                print(json.dumps({"program": name, "instruction": i["name"],
+                                  "estimated_cycles": i["cycles"],
+                                  "stage": stage.get(i["name"]),
+                                  "part": part.get(i["name"]),
+                                  "emitter": i["emitter"]}), flush=True)
 
 
 if __name__ == "__main__":
