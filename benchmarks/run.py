@@ -13,7 +13,6 @@ jitted op where timing is meaningful; derived = the figure's headline metric).
                     incl. the importance-weighted (fisher/gradmatch) form
   lora_payload      §3.2 LoRA-only sync payload vs full-model payload
   gossip_spectrum   consensus rate (spectral gap) per topology
-  sync_roundtrip    host-sim 4-node sync wall time (propose+gate+commit)
   engine_roundtrip  jitted stacked engine round (local steps + gated sync)
   overlap_roundtrip double-buffered stale-by-one rounds vs serial rounds
   dynamic_membership SwarmSession join/leave schedule: wall time per round +
@@ -254,29 +253,9 @@ def gossip_spectrum():
         print(f"gossip_gap_{topo_name}{n},0,{spectral_gap(W):.4f}")
 
 
-def sync_roundtrip():
-    from repro.configs.base import SwarmConfig
-    from repro.core.swarm import NodeState, SwarmLearner
-    rng = np.random.default_rng(0)
-    tree = lambda: {"w": jnp.asarray(rng.normal(0, 1, (64, 64)), jnp.float32)}
-    nodes = [NodeState(params=tree(), opt_state=None, data_size=100)
-             for _ in range(4)]
-    sw = SwarmLearner(
-        SwarmConfig(n_nodes=4, sync_every=1, lora_only=False, topology="full"),
-        train_step_fn=lambda p, o, b, s: (p, o, {}),
-        eval_fn=lambda p, v: 1.0, nodes=nodes)
-    sw.sync([1, 1, 1, 1])  # compile the jitted propose/commit outside timing
-    t0 = time.perf_counter()
-    reps = 10
-    for _ in range(reps):
-        sw.sync([1, 1, 1, 1])
-    us = (time.perf_counter() - t0) / reps * 1e6
-    print(f"sync_roundtrip_4node_host,{us:.1f},propose+gate+commit")
-
-
 def engine_roundtrip():
     """The jitted stacked engine: sync_every local steps + propose + gate +
-    fused commit as ONE compiled call (vs sync_roundtrip's host-driven sync)."""
+    fused commit as ONE compiled call."""
     from repro.configs.base import SwarmConfig
     from repro.core.engine import SwarmEngine
     rng = np.random.default_rng(0)
@@ -309,7 +288,7 @@ def engine_roundtrip():
 
 
 def overlap_roundtrip(reps: int = 10):
-    """Stale-by-one double-buffered rounds vs serial rounds, host backend:
+    """Stale-by-one double-buffered rounds vs serial rounds, engine backend:
     the overlap schedule must cost no more than serial (same work + one add;
     on hardware with async collectives the merge then hides behind the next
     round's local steps)."""
@@ -1096,13 +1075,13 @@ def overlap_roundtrip_smoke():
 
 ALL = [fig2_node0, fig3_node3, fig4_node2_25pct, scarcity_node3_5pct,
        tbl_dbi, tbl_minority, merge_kernel, lora_payload, gossip_spectrum,
-       sync_roundtrip, engine_roundtrip, overlap_roundtrip,
+       engine_roundtrip, overlap_roundtrip,
        dynamic_membership, spmd_parity, swarm_sync, ring_sync_parity,
        mesh_wire, hier_sync, serve, hetero_swarm, fault_matrix]
 
 # seconds-scale subset covering every benchmark family (tier-1 smoke test)
-SMOKE = [merge_kernel_smoke, gossip_spectrum, sync_roundtrip,
-         engine_roundtrip, overlap_roundtrip_smoke, dynamic_membership_smoke,
+SMOKE = [merge_kernel_smoke, gossip_spectrum, engine_roundtrip,
+         overlap_roundtrip_smoke, dynamic_membership_smoke,
          spmd_parity_smoke, swarm_sync_smoke, ring_sync_parity_smoke,
          mesh_wire_smoke, hier_sync_smoke, serve_smoke, hetero_swarm_smoke,
          fault_matrix_smoke]

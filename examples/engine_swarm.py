@@ -1,11 +1,11 @@
 """The compiled swarm session in ~50 lines.
 
-Where quickstart.py drives arbitrary Python callables (`backend="host"`),
-this example hands the whole P2P-SL schedule to the default engine backend
-of `SwarmSession.run_rounds`: every round — `sync_every` vmapped local
-steps, in-graph validation of local and merged params, the 80% gate, and
-the fused Pallas commit — is part of ONE compiled program; rounds are
-scanned with zero host round-trips. Mid-run membership changes
+Where quickstart.py calls `SwarmSession.round` once a round, this example
+hands the whole P2P-SL schedule to `SwarmSession.run_rounds` on the default
+engine backend: every round — `sync_every` vmapped local steps, in-graph
+validation of local and merged params, the 80% gate, and the fused Pallas
+commit — is part of ONE compiled program; rounds are scanned with zero
+host round-trips. Mid-run membership changes
 (`session.leave` / `session.join`) are pure state updates: the second
 `run_rounds` call below reuses the already-compiled round.
 

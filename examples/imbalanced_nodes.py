@@ -14,13 +14,17 @@ Also demonstrates DYNAMIC MEMBERSHIP: node 3 leaves the swarm mid-training
 via ``session.leave(3)`` and re-joins later via ``session.join(3)`` (the
 paper's §3.1 join/leave semantics — runtime state, not reconfiguration).
 
+Each round — local steps, the in-graph AUC gate, the fused commit — runs as
+one compiled `SwarmSession.round` call over stacked ``[T, N, ...]`` batches;
+while node 3 is away it gets padding that its step leaves unapplied.
+
 Note on fisher/gradmatch here: importance mass comes from the strategy's
 in-graph Δθ² accumulation (no host-side Fisher loop). Because this example
 trains with AdamW — whose step sizes are ~lr regardless of gradient scale —
 that proxy is closer to update-activity weighting than exact curvature, so
 fisher/gradmatch land nearer fedavg than they would with true squared-grad
-Fishers (set ``node.fisher`` explicitly to supply those; see
-`merge_impl.FisherStrategy`).
+Fishers (a train step returning its grads as a fourth value supplies those;
+see `SwarmEngine.local_steps`).
 
 Run:  PYTHONPATH=src python examples/imbalanced_nodes.py [--steps 150]
 """
@@ -33,7 +37,7 @@ import numpy as np
 from repro.configs.base import SwarmConfig, TrainConfig
 from repro.core.session import SwarmSession
 from repro.data import batches, make_histo_dataset, shard_to_nodes
-from repro.metrics import classify_report
+from repro.metrics import gate_metric_fn
 from repro.models.cnn import bce_loss, forward_cnn, init_cnn
 from repro.optim import adamw_init, adamw_update
 
@@ -53,35 +57,31 @@ def run(swarm_cfg, steps, dynamic=False, seed=0):
     def loss(params, x, y):
         return bce_loss(forward_cnn(params, x), jax.nn.one_hot(y, 3))
 
-    @jax.jit
-    def train_step_(params, opt, x, y):
-        l, g = jax.value_and_grad(loss)(params, x, y)
-        params, opt = adamw_update(params, g, opt, tc, 1e-3)
-        return params, opt, l
-
     def train_step(params, opt, batch, step):
-        x, y = batch
-        params, opt, l = train_step_(params, opt, jnp.asarray(x), jnp.asarray(y))
-        return params, opt, {"loss": l}
+        x, y, live = batch   # live False: padding, the step is not applied
+        l, g = jax.value_and_grad(loss)(params, x, y)
+        new_params, new_opt = adamw_update(params, g, opt, tc, 1e-3)
+        keep = lambda new, old: jnp.where(live, new, old)
+        return (jax.tree.map(keep, new_params, params),
+                jax.tree.map(keep, new_opt, opt), {"loss": l})
 
-    @jax.jit
-    def predict(params, x):
-        return jax.nn.sigmoid(forward_cnn(params, x))
+    auc = gate_metric_fn("auc")
 
     def eval_fn(params, val):
         x, y = val
-        return classify_report(np.asarray(predict(params, jnp.asarray(x))),
-                               y)["auc"]
+        return auc(jax.nn.sigmoid(forward_cnn(params, x)), y)
 
     params = init_cnn(jax.random.key(42), None, growth=8, stem=16,
                       feat_dim=96, hidden=32)
-    sw = SwarmSession(swarm_cfg, train_step, eval_fn, backend="host",
+    sw = SwarmSession(swarm_cfg, train_step, eval_fn,
                       params=params, opt_state=adamw_init(params),
                       data_sizes=[len(s[1]) for s in shards])
 
     rngs = [np.random.default_rng(seed * 10 + i) for i in range(4)]
     iters = [iter(()) for _ in range(4)]
-    vals = [(s[0][:48], s[1][:48]) for s in shards]
+    vals = tuple(jnp.asarray(np.stack([s[k][:48] for s in shards]))
+                 for k in (0, 1))
+    pad = (np.zeros_like(shards[0][0][:16]), np.zeros_like(shards[0][1][:16]))
     t = swarm_cfg.sync_every
     for round_start in range(0, steps, t):
         if dynamic:  # node 3 leaves at 1/3, rejoins at 2/3 of the run
@@ -89,27 +89,30 @@ def run(swarm_cfg, steps, dynamic=False, seed=0):
                 sw.leave(3)
             else:
                 sw.join(3)
+        active = sw.active
         round_batches = []
         for _ in range(min(t, steps - round_start)):
             bs = []
             for i, s in enumerate(shards):
-                if not sw.active[i]:
-                    bs.append(None)
+                if not active[i]:
+                    bs.append(pad + (False,))
                     continue
                 try:
                     b = next(iters[i])
                 except StopIteration:
                     iters[i] = batches(s[0], s[1], 16, rngs[i])
                     b = next(iters[i])
-                bs.append(b)
+                bs.append(b + (True,))
             round_batches.append(bs)
-        # fisher/gradmatch importance mass accumulates inside the round
-        # via the configured MergeStrategy — no host-side estimation loop
-        sw.round(round_batches, vals)
+        # [T][N] (x, y, live) -> [T, N, ...] per field; fisher/gradmatch
+        # importance mass accumulates inside the round via the configured
+        # MergeStrategy
+        sw.round(tuple(jnp.asarray(np.array([[b[k] for b in bs]
+                                             for bs in round_batches]))
+                       for k in range(3)), vals)
 
-    aucs = [classify_report(np.asarray(predict(p, jnp.asarray(test_x))),
-                            test_y)["auc"] for p in sw.node_params]
-    return aucs
+    score = jax.jit(lambda p, x, y: auc(jax.nn.sigmoid(forward_cnn(p, x)), y))
+    return [float(score(p, test_x, test_y)) for p in sw.node_params]
 
 
 def main():

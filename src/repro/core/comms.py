@@ -12,7 +12,7 @@ place those decisions live; every backend routes through it:
     shard layout), each with an analytic per-device bytes/sync formula
     (:class:`SyncSchedule`). :func:`pick_schedule` argmins the model — the
     engine's gossip backend dispatches on the winner at trace time, and the
-    engine/host backends surface the equivalent schedule (``simulated=True``)
+    engine backend surfaces the equivalent schedule (``simulated=True``)
     so logs and benchmarks always report predicted wire cost.
   * **Quantized error-feedback wire** (``SwarmConfig.wire_dtype``) — peers
     exchange int8/bf16-quantized parameter *deltas* against a shared
@@ -158,7 +158,7 @@ class SyncSchedule:
     payload_factor: float
     wire_dtype: str = "f32"
     wire_block: int = 512
-    simulated: bool = False  # engine/host backend: the SPMD-equivalent cost
+    simulated: bool = False  # engine backend: the SPMD-equivalent cost
     # Two-level (pod, node) split of payload_factor. cross_factor is None on
     # a flat mesh (one bandwidth domain — everything counts as intra). On a
     # 2-D mesh: cross_factor·P values cross pods at wire_dtype and

@@ -1,11 +1,8 @@
 """Jitted stacked swarm engine: the whole P2P-SL round as ONE compiled program.
 
 The paper's loop (§3.1) — `sync_every` local steps, peer exchange, 80 %-
-validation gated commit — was previously host-simulated as a Python loop over
-nodes: every sync unstacked N param copies, ran per-node ``eval_fn`` with
-``float(...)`` device round-trips, and merged through an unfused mix + where.
-This module compiles the round end-to-end over **stacked pytrees** (leading
-node axis N):
+validation gated commit — runs here end-to-end over **stacked pytrees**
+(leading node axis N):
 
   local steps   ``jax.vmap`` of the user train step over the node axis, or
                 the stacked form the step offers where one TPU holds
@@ -15,7 +12,7 @@ node axis N):
                 configured `merge_impl.MergeStrategy` accumulates per-node
                 importance statistics (Fisher mass) in the same scan;
   propose       strategy-driven: mixing-matrix contraction or Fisher-
-                weighted merge (host backend) / mesh collectives (gossip
+                weighted merge (engine backend) / mesh collectives (gossip
                 backend, `core.gossip`) — every merge method in-graph;
   gate          in-graph validation metrics for local AND merged params
                 (``jax.vmap`` of a traceable ``eval_fn``, or its stacked
@@ -30,12 +27,10 @@ API
 ---
 **The public entry point is `repro.core.session.SwarmSession`**, which wraps
 this engine behind a single `SwarmState` pytree (params, opt state, strategy
-stats, runtime active mask, rng, counters) shared with the host and gossip
-backends, and adds the lifecycle layer: ``join``/``leave`` as pure state
-updates (zero retraces — the mixing matrix is built in-graph by
-`topology.mixing_matrix_traced` from the runtime mask) and
-``save``/``restore`` checkpointing. Constructing ``SwarmEngine`` directly
-still works but is a deprecated spelling of ``SwarmSession(...)``.
+stats, runtime active mask, rng, counters) on both backends, and adds the
+lifecycle layer: ``join``/``leave`` as pure state updates (zero retraces —
+the mixing matrix is built in-graph by `topology.mixing_matrix_traced` from
+the runtime mask) and ``save``/``restore`` checkpointing.
 
 ``SwarmEngine(cfg, train_step_fn, eval_fn, *, data_sizes, backend, ...)``
 
@@ -57,16 +52,13 @@ still works but is a deprecated spelling of ``SwarmSession(...)``.
       baselines, remainder steps) → ``(params, opt_state, metrics, stats)``;
       stats stays None unless accumulators are threaded in.
   * ``engine.propose(stacked, active, fishers)`` / ``engine.sync(...)``
-      the pure pieces, reused by `SwarmLearner` (host) and
-      `launch.train.make_swarm_sync_step` (SPMD gossip backend).
+      the pure pieces, reused by `launch.train.make_swarm_sync_step`.
 
 ``train_step_fn(params, opt_state, batch, step) -> (params, opt_state,
 metrics)`` — or the opt-in true-Fisher 4-tuple form that additionally
 returns per-step ``grads`` (consumed as exact squared gradients by
 fisher/gradmatch accumulation) — and ``eval_fn(params, val) -> scalar in
-[0, 1]`` must be jax-traceable; arbitrary host callables stay on the
-`SwarmLearner` slow path, which still shares `strategy_propose` /
-`host_commit` below.
+[0, 1]`` must be jax-traceable.
 
 Roofline
 --------
@@ -93,8 +85,6 @@ collectives the sync cost hides entirely behind the next T local steps.
 from __future__ import annotations
 
 from typing import Callable, Optional, Sequence
-
-import functools
 
 import jax
 import jax.numpy as jnp
@@ -197,7 +187,7 @@ def site_vmap(fn: Callable, in_axes: tuple) -> Callable:
 
 
 # ---------------------------------------------------------------------------
-# pure building blocks (shared by engine, SwarmLearner, and SPMD paths)
+# pure building blocks (shared by the engine and the SPMD paths)
 # ---------------------------------------------------------------------------
 
 def mixing_matrix(cfg: SwarmConfig, data_sizes: Sequence[float],
@@ -316,40 +306,6 @@ def host_commit(stacked, candidate, W, gates, cfg: SwarmConfig, *, imp=None,
     return gated_commit(candidate, stacked, gates)
 
 
-# jitted wrappers for the SwarmLearner host path (cfg hashes — frozen dataclass)
-
-@functools.partial(jax.jit, static_argnames=("cfg",))
-def _propose_jit(stacked, W, fishers, weights, rows, cfg):
-    return strategy_propose(stacked, cfg, W, fishers=fishers, weights=weights,
-                            rows=rows)
-
-
-def propose_host(stacked, cfg: SwarmConfig, W, *, fishers=None, weights=None,
-                 rows=None):
-    """One-call jitted propose (stack→mix fused by XLA; no eager dispatch).
-
-    Returns ``(candidate, W_commit, imp)`` — see :func:`strategy_propose`.
-    """
-    w = None if weights is None else jnp.asarray(weights, jnp.float32)
-    return _propose_jit(stacked, jnp.asarray(W, jnp.float32), fishers, w,
-                        rows, cfg)
-
-
-@functools.partial(jax.jit, static_argnames=("cfg", "block", "interpret"))
-def _commit_jit(stacked, candidate, W, gates, imp, cfg, block, interpret):
-    return host_commit(stacked, candidate, W, gates, cfg, imp=imp,
-                       block=block, interpret=interpret)
-
-
-def commit_host(stacked, candidate, W, gates, cfg: SwarmConfig, *, imp=None,
-                block: int = DEFAULT_BLOCK, interpret: Optional[bool] = None):
-    if interpret is None:
-        interpret = default_interpret()
-    return _commit_jit(stacked, candidate, jnp.asarray(W, jnp.float32),
-                       jnp.asarray(gates).astype(bool), imp, cfg, block,
-                       interpret)
-
-
 # ---------------------------------------------------------------------------
 # the engine
 # ---------------------------------------------------------------------------
@@ -357,7 +313,7 @@ def commit_host(stacked, candidate, W, gates, cfg: SwarmConfig, *, imp=None,
 class SwarmEngine:
     """Compiled stacked swarm: vmapped local steps + in-graph gated sync.
 
-    backend="host"    merge via mixing-matrix contraction, commit via the
+    backend="engine"  merge via mixing-matrix contraction, commit via the
                       fused Pallas kernel (N param copies on one device —
                       the paper-repro and benchmark path).
     backend="gossip"  merge via `core.gossip` mesh collectives (leading node
@@ -369,12 +325,14 @@ class SwarmEngine:
     def __init__(self, cfg: SwarmConfig, train_step_fn: Optional[Callable],
                  eval_fn: Optional[Callable], *,
                  data_sizes: Optional[Sequence[float]] = None,
-                 backend: str = "host", mesh=None, axis: Optional[str] = None,
+                 backend: str = "engine", mesh=None,
+                 axis: Optional[str] = None,
                  param_specs=None, block: int = DEFAULT_BLOCK,
                  interpret: Optional[bool] = None,
                  strategy: Optional[merge_lib.MergeStrategy] = None):
-        if backend not in ("host", "gossip"):
-            raise ValueError(f"unknown backend {backend!r}")
+        if backend not in ("engine", "gossip"):
+            raise ValueError(f"unknown backend {backend!r}; choose "
+                             '"engine" or "gossip"')
         if backend == "gossip" and (mesh is None or axis is None):
             raise ValueError("gossip backend needs mesh and axis")
         self.cfg = cfg
@@ -410,7 +368,8 @@ class SwarmEngine:
                              f"[0, 1], got {self.fairness_floor}")
         # the comms cost model picks the sync schedule at trace time: for
         # the gossip backend this decides which collectives propose lowers
-        # to; for host it reports the SPMD-equivalent wire cost (simulated).
+        # to; for engine it reports the SPMD-equivalent wire cost
+        # (simulated).
         # model-sharded payloads (inner param specs) drop the q8 psum
         # reductions from the candidate set — they chunk the globally-
         # flattened payload, which a model axis would scramble.
@@ -460,7 +419,7 @@ class SwarmEngine:
         # what its vmap returns (`histo._make_model_fns` folds the sites
         # into the DenseNet's lanes). It is taken where one TPU holds
         # several sites; zoo lists and the gossip backend keep their paths.
-        fold = backend == "host" and cfg.n_nodes > 1 and lanes_tiled()
+        fold = backend == "engine" and cfg.n_nodes > 1 and lanes_tiled()
 
         def _form(fn):
             if fn is None:
@@ -565,7 +524,7 @@ class SwarmEngine:
         w = active_weights_traced(self.data_sizes, a)
         if self.strategy.uses_stats and fishers is None:
             # no evidence for any node -> zero mass everywhere, which the
-            # eps floor turns into a uniform mean (= SwarmLearner default)
+            # eps floor turns into a uniform mean
             fishers = jax.tree.map(jnp.zeros_like, stacked)
         fishers = self.strategy.finalize_mass(fishers, a)
         rows = None
@@ -624,7 +583,7 @@ class SwarmEngine:
         wire_cast = None if self.wire_dtype == "f32" or q8 else self.wire_dtype
         if q8 and wire is None:
             wire = self._auto_wire(stacked, None)
-        # merge="mean" averages uniformly (host W is uniform); only fedavg
+        # merge="mean" averages uniformly (its W is uniform); only fedavg
         # folds dataset sizes into the psum weights
         sizes = (jnp.asarray(self.data_sizes, jnp.float32)
                  if cfg.merge == "fedavg"
@@ -677,7 +636,7 @@ class SwarmEngine:
             else:
                 # topology-restricted weighted merge on the mesh: per-row
                 # ratio over graph-neighbour contributions only, matching
-                # the host backend's `topo_weighted_merge` oracle
+                # the engine backend's `topo_weighted_merge` rows
                 rows = self.strategy.topo_rows(self._traced_W(a), w)
                 if q8:
                     fn = (gossip.ring_topo_fisher_gossip_q8
@@ -767,7 +726,7 @@ class SwarmEngine:
             return wire
         payload = (split_adapters(params)[0] if self._split_lora
                    else params)
-        if self.backend == "host":
+        if self.backend == "engine":
             return comms.init_wire(payload)
         if self.wire_dtype != "int8":
             return None
@@ -784,7 +743,7 @@ class SwarmEngine:
         ``wire``: the error-feedback wire state from `core.comms` /
         `core.gossip` — peers merge the int8/bf16 wire reconstruction θ̂'
         instead of the exact params and rejected nodes keep exact f32
-        locals. On the host backend the commit runs through the fused Pallas
+        locals. On the engine backend the commit runs through the fused Pallas
         quantize→merge→dequantize kernel; on the gossip backend the q8
         collective schedules advance the sharded mesh EF state in-graph.
         The advanced state is returned in the log under ``"wire"``.
@@ -795,7 +754,7 @@ class SwarmEngine:
         detects the damage and the sender is quarantined for the round
         (reject-and-keep-local: excluded from the merge AND gated off, so
         nobody — including the sender — commits corrupted bytes). Only the
-        wire-carrying host/engine path supports injection; pass drops
+        wire-carrying engine backend supports injection; pass drops
         (membership masking) elsewhere. Both ``faults`` fields are runtime
         data, so arming/disarming never retraces. ``shared``: the frozen
         base the gate's eval takes as its last argument (see
@@ -805,7 +764,7 @@ class SwarmEngine:
         a = (jnp.ones((n,), bool) if active is None
              else jnp.asarray(active).astype(bool))
         wire = self._auto_wire(params, wire)
-        use_wire = wire is not None and self.backend == "host"
+        use_wire = wire is not None and self.backend == "engine"
         use_mesh_wire = wire is not None and self.backend == "gossip"
         if faults is not None and not use_wire:
             raise ValueError(
@@ -914,7 +873,7 @@ class SwarmEngine:
                 committed = (combine(committed_payload, base)
                              if base is not None else committed_payload)
                 log["wire"] = new_wire
-            elif self.backend == "host":
+            elif self.backend == "engine":
                 committed = host_commit(params, candidate, W, gates, self.cfg,
                                         imp=imp, block=self.block,
                                         interpret=self.interpret)

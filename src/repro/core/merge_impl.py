@@ -3,7 +3,7 @@
 All merges operate on **stacked pytrees**: every leaf carries a leading node
 axis N. This single representation serves both execution modes:
 
-  * host-simulated swarm (paper repro, N param copies on one device),
+  * the engine backend (paper repro, N param copies on one device),
   * SPMD swarm (leading axis sharded over the mesh's `node`/`pod` axis, where
     the einsum against the mixing matrix lowers to the gossip collectives).
 
@@ -212,8 +212,8 @@ class MergeStrategy:
         """Mask-then-finalize, in that order: a departed node's (possibly
         huge) stale mass must be zeroed BEFORE normalization, or it drags
         the normalization mean and drowns the survivors in the eps floor.
-        Every merge path (engine host, engine gossip, SwarmLearner) calls
-        this instead of hand-sequencing the two steps."""
+        Every merge path (the engine and gossip backends) calls this
+        instead of hand-sequencing the two steps."""
         if fishers is None:
             return None
         if active is not None:

@@ -1,10 +1,7 @@
 """SwarmSession: ONE backend-agnostic entry point for P2P swarm learning.
 
-The paper ships three ways to run the same algorithm — the host-simulated
-`SwarmLearner` loop, the compiled `SwarmEngine`, and the SPMD gossip path in
-`launch.train` — each with its own constructor, state threading, and
-checkpoint story. `SwarmSession` collapses them behind a single API driven by
-one pytree, :class:`SwarmState`:
+`SwarmSession` drives the compiled `SwarmEngine` round on one device or on
+a mesh behind a single API driven by one pytree, :class:`SwarmState`:
 
     session = SwarmSession(cfg, train_step, eval_fn, params=params,
                            data_sizes=sizes)          # backend="engine"
@@ -15,16 +12,14 @@ one pytree, :class:`SwarmState`:
     session = SwarmSession.restore("ckpt.msgpack", cfg, train_step, eval_fn,
                                    params=params, data_sizes=sizes)
 
-Backends (construction-time choice; the API is identical):
+Backends (construction-time choice; the API and its contract are the
+same on both — stacked ``[..., N, ...]`` arrays in, logs of device arrays
+out):
 
   * ``"engine"``  — the compiled stacked round (N param copies on one
     device): vmapped local steps, in-graph gate, fused Pallas commit.
   * ``"gossip"``  — the same round with the merge realized as mesh
     collectives (leading node axis sharded over ``axis``).
-  * ``"host"``    — arbitrary (non-traceable) Python ``train_step_fn`` /
-    ``eval_fn`` callables via the `SwarmLearner` loop; the compatibility
-    path. Batches are ``[T][N]`` nested lists of per-node batch objects and
-    ``val`` is an ``[N]`` list, instead of stacked arrays.
 
 Dynamic membership is **runtime state**: ``session.join(i)`` / ``leave(i)``
 flip one element of ``SwarmState.active`` — a device array consumed by the
@@ -59,7 +54,7 @@ logger = logging.getLogger(__name__)
 
 @dataclass
 class SwarmState:
-    """The whole swarm as one pytree (every backend consumes and returns it).
+    """The whole swarm as one pytree (both backends consume and return it).
 
     params / opt_state / stats are **stacked** pytrees (leading node axis N);
     ``stats`` carries the merge strategy's importance accumulators (None for
@@ -120,9 +115,8 @@ class SwarmSession:
     cfg : SwarmConfig
     train_step_fn : ``(params, opt_state, batch, step) -> (params, opt_state,
         metrics)`` — or the opt-in true-Fisher 4-tuple form that additionally
-        returns per-step grads. Must be traceable for the engine/gossip
-        backends; arbitrary Python for ``backend="host"``.
-    eval_fn : ``(params, val) -> scalar in [0, 1]`` (same traceability rule).
+        returns per-step grads. Must be jax-traceable.
+    eval_fn : ``(params, val) -> scalar in [0, 1]``, jax-traceable.
         Both fns may instead be a LIST of ``n_nodes`` per-node closures
         (model zoo: heterogeneous frozen backbones captured per closure,
         shared adapter payload as the state — ``cfg.payload="lora"``,
@@ -131,7 +125,7 @@ class SwarmSession:
         list of N pytrees, or — with ``stacked=True`` — an already-stacked
         pytree with leading node axis.
     data_sizes : per-node dataset sizes (fedavg / weighted-merge weights).
-    backend : ``"engine"`` (default) | ``"gossip"`` | ``"host"``.
+    backend : ``"engine"`` (default) | ``"gossip"``.
     mesh / axis / param_specs : gossip backend placement; ``axis`` is a mesh
         axis name, or a 2-tuple ``("pod", "node")`` on a two-level mesh —
         gossip then runs over the joint axis and the per-link-class cost
@@ -154,15 +148,16 @@ class SwarmSession:
                  interpret: Optional[bool] = None, strategy=None,
                  seed: Optional[int] = None, stacked: bool = False,
                  shared=None):
-        if backend not in ("engine", "gossip", "host"):
-            raise ValueError(f"unknown backend {backend!r}")
+        if backend not in ("engine", "gossip"):
+            raise ValueError(f"unknown backend {backend!r}; choose "
+                             '"engine" or "gossip"')
         zoo = isinstance(train_step_fn, (list, tuple)) or isinstance(
             eval_fn, (list, tuple))
         if shared is not None and (backend != "engine" or zoo):
             raise ValueError("a shared frozen base needs the engine backend "
                              "and one train step and eval for every site: "
-                             "the gossip backend shards sites over devices, "
-                             "the host loop and zoo closures take no base")
+                             "the gossip backend shards sites over devices "
+                             "and zoo closures take no base")
         self.cfg = cfg
         self.backend = backend
         self.train_step_fn = train_step_fn
@@ -180,54 +175,10 @@ class SwarmSession:
         if stacked_params is None:
             raise ValueError("SwarmSession needs initial params")
         rng = jax.random.PRNGKey(cfg.seed if seed is None else seed)
-        wire_dtype = comms.validate_wire_dtype(
-            getattr(cfg, "wire_dtype", "f32"))
-        if wire_dtype != "f32" and backend == "host":
-            raise ValueError(
-                "wire_dtype compression needs a compiled backend "
-                '(backend="engine" carries the error-feedback reference; '
-                '"gossip" carries the sharded mesh EF state for int8 and '
-                "casts bf16); the host loop is uncompressed")
-        if comms.payload_mode(cfg) == "lora" and backend == "host":
-            raise ValueError(
-                'payload="lora" (adapter-only state, heterogeneous '
-                "backbones in per-node closures) needs a compiled backend; "
-                "the host loop threads full per-node param pytrees")
-        if (backend == "host"
-                and (isinstance(train_step_fn, (list, tuple))
-                     or isinstance(eval_fn, (list, tuple)))):
-            raise ValueError(
-                "per-node closure lists (model zoo) are engine-backend "
-                "only; the host loop applies one callable to every node")
-
-        if backend == "host":
-            from repro.core.swarm import NodeState, SwarmLearner
-            sizes = (np.ones(n) if data_sizes is None
-                     else np.asarray(data_sizes, np.float64))
-            nodes = [NodeState(params=p, opt_state=o, data_size=float(s))
-                     for p, o, s in zip(
-                         merge_lib.unstack_params(stacked_params, n),
-                         (merge_lib.unstack_params(stacked_opt, n)
-                          if stacked_opt is not None else [None] * n),
-                         sizes)]
-            self._learner = SwarmLearner(cfg, train_step_fn, eval_fn, nodes)
-            self._rng = rng
-            self._round_ct = 0
-            self.engine = None
-            self.sync_schedule = comms.pick_schedule(cfg, simulated=True)
-            self.payload_params = comms.payload_param_count(
-                stacked_params, comms.split_payload_at_sync(cfg), n)
-            self.predicted_sync_bytes = self.sync_schedule.bytes_per_sync(
-                self.payload_params)
-            self.predicted_link_bytes = self.sync_schedule.bytes_by_link_class(
-                self.payload_params)
-            return
-
         self.engine = SwarmEngine(
             cfg, train_step_fn, eval_fn, data_sizes=data_sizes,
-            backend="gossip" if backend == "gossip" else "host",
-            mesh=mesh, axis=axis, param_specs=param_specs, block=block,
-            interpret=interpret, strategy=strategy)
+            backend=backend, mesh=mesh, axis=axis, param_specs=param_specs,
+            block=block, interpret=interpret, strategy=strategy)
         # error-feedback wire state for the quantized sync — the engine
         # backend carries the θ̂ reference (shaped like the sync payload,
         # adapters only under lora_only); the gossip backend carries the
@@ -266,7 +217,7 @@ class SwarmSession:
     # the device that owns the site. The state, each round's batches and
     # ``val`` are placed there once, exactly as the compiled round returns
     # them, so the second round reuses the first one's program. With data or
-    # model axes inside a site (param_specs), and on the other backends,
+    # model axes inside a site (param_specs), and on the engine backend,
     # placement is left to jit.
 
     def _on_mesh(self, tree, lead: int = 0):
@@ -310,57 +261,20 @@ class SwarmSession:
 
     @property
     def state(self) -> SwarmState:
-        if self.backend != "host":
-            return self._state
-        lr = self._learner
-        strategy = lr.strategy
-        stats = None
-        if strategy.uses_stats:
-            stats = merge_lib.stack_params([
-                nd.fisher_stats if nd.fisher_stats is not None
-                else strategy.init_stats(nd.params)
-                for nd in lr.nodes])
-        opt = (None if all(nd.opt_state is None for nd in lr.nodes)
-               else merge_lib.stack_params([nd.opt_state for nd in lr.nodes]))
-        return SwarmState(
-            params=merge_lib.stack_params([nd.params for nd in lr.nodes]),
-            opt_state=opt, stats=stats,
-            active=jnp.asarray([nd.active for nd in lr.nodes]),
-            rng=self._rng, round=jnp.asarray(self._round_ct, jnp.int32),
-            step=jnp.asarray(lr.step, jnp.int32))
+        return self._state
 
     def load_state(self, state: SwarmState) -> None:
-        """Replace the session's state (all backends)."""
-        if self.backend != "host":
-            self._state = self._place_state(state)
-            return
-        lr = self._learner
-        n = self.cfg.n_nodes
-        ps = merge_lib.unstack_params(state.params, n)
-        os_ = (merge_lib.unstack_params(state.opt_state, n)
-               if state.opt_state is not None else [None] * n)
-        sts = (merge_lib.unstack_params(state.stats, n)
-               if state.stats is not None else [None] * n)
-        active = np.asarray(state.active)
-        for i, nd in enumerate(lr.nodes):
-            nd.params, nd.opt_state, nd.fisher_stats = ps[i], os_[i], sts[i]
-            nd.active = bool(active[i])
-        self._rng = jnp.asarray(state.rng)
-        self._round_ct = int(state.round)
-        lr.step = int(state.step)
+        """Replace the session's state."""
+        self._state = self._place_state(state)
 
     @property
     def node_params(self):
         """Per-node (unstacked) parameter pytrees."""
-        if self.backend == "host":
-            return [nd.params for nd in self._learner.nodes]
         return merge_lib.unstack_params(self._state.params, self.cfg.n_nodes)
 
     @property
     def active(self) -> np.ndarray:
-        if self.backend == "host":
-            return np.asarray([nd.active for nd in self._learner.nodes])
-        return np.asarray(self.state.active)
+        return np.asarray(self._state.active)
 
     # -- dynamic membership (runtime data; never recompiles) -----------------
 
@@ -372,24 +286,16 @@ class SwarmSession:
         """Node leaves the swarm: excluded from every merge (its params and
         importance mass enter nobody's candidate, its own params pass through
         commits untouched). Local training is governed by DATA, not
-        membership — on every backend a departed node keeps training on
-        whatever batches the caller still supplies; feed it ``None`` (host)
-        or padding it can ignore (engine) to pause it entirely."""
+        membership — a departed node keeps training on whatever batches the
+        caller still supplies; feed it padding it can ignore to pause it."""
         self._set_active_index(node, False)
 
     def set_active(self, mask) -> None:
-        if self.backend == "host":
-            for i, v in enumerate(np.asarray(mask)):
-                self._learner.nodes[i].active = bool(v)
-            return
         self._state = dataclasses.replace(
             self._state,
             active=self._replicated(jnp.asarray(mask).astype(bool)))
 
     def _set_active_index(self, node: int, value: bool) -> None:
-        if self.backend == "host":
-            self._learner.nodes[node].active = value
-            return
         self._state = dataclasses.replace(
             self._state,
             active=self._replicated(self._state.active.at[node].set(value)))
@@ -408,10 +314,10 @@ class SwarmSession:
         schedule-shaped sharded structure whose neighbour replicas must
         track the reference bit-exactly, so per-node surgery is unsafe —
         the whole mesh wire resets (`gossip.reset_mesh_wire`) and EF
-        re-settles for everyone. No-op without wire state or on the host
-        backend (uncompressed). Pure data update: never retraces.
+        re-settles for everyone. No-op without wire state. Pure data
+        update: never retraces.
         """
-        if self.backend == "host" or self._state.wire is None:
+        if self._state.wire is None:
             return
         wire = self._state.wire
         if self.backend == "engine" and node is not None:
@@ -422,7 +328,7 @@ class SwarmSession:
             new_wire = gossip.reset_mesh_wire(wire)
         self._state = dataclasses.replace(self._state, wire=new_wire)
 
-    # -- compiled round bodies (engine / gossip backends) --------------------
+    # -- compiled round bodies -----------------------------------------------
     # Thin SwarmState adapters over the engine's round implementations — the
     # serial and stale-by-one overlap scan bodies have exactly one home
     # (`SwarmEngine._round` / `_run_rounds` / `_run_local`).
@@ -470,46 +376,35 @@ class SwarmSession:
     # -- drivers -------------------------------------------------------------
 
     def round(self, batches, val, faults=None):
-        """One full round: ``sync_every`` local steps + gated sync.
+        """One full round: ``sync_every`` local steps + gated sync, as one
+        compiled call.
 
-        engine/gossip: ``batches`` is a stacked ``[T, N, ...]`` pytree, the
-        whole round runs as one compiled call, and the log holds device
-        arrays ``gates`` / ``metric_local`` / ``metric_merged`` (each [N])
-        plus ``train`` ([T, N] per-step metrics). host: ``batches`` is a
-        ``[T][N]`` nested list of per-node batch objects, ``val`` an ``[N]``
-        list, and the log is the `SwarmLearner` sync record — same
-        ``gates``/``metric_local``/``metric_merged`` keys as Python lists,
-        plus ``step``/``spectral_gap``; per-step train metrics live in each
-        node's ``history`` instead of a ``train`` key.
+        ``batches`` is a stacked ``[T, N, ...]`` pytree (a site that should
+        not train gets padding its step ignores) and ``val`` a stacked
+        ``[N, ...]`` pytree. Returns the round's log of device arrays:
+        ``gates`` / ``metric_local`` / ``metric_merged`` (each [N]) and
+        ``train`` ([T, N] per-step metrics), plus ``quorum_ok`` (with
+        ``cfg.quorum``), ``fairness_ok``/``worst_site`` (with
+        ``cfg.fairness_floor``) and ``wire_ok`` (with ``faults``).
 
         ``faults``: optional `repro.faults.signals.FaultSignals` for
         in-graph corrupt-wire injection (engine backend with a quantized
         wire only — see `SwarmEngine.sync`). Thread a signal (possibly
         `faults.idle_signals`) every round to keep one compiled trace.
         """
-        if self.backend == "host" and faults is not None:
-            raise ValueError(
-                "in-graph fault injection (faults=) needs a compiled "
-                "backend; lower corrupt events to drops on the host loop")
         with tracing.span("round", id=self._dispatched):
             self._dispatched += 1
-            if self.backend == "host":
-                return self._host_round(batches, val)
             self._state, out = self._round_jit(
                 self._state, self._on_mesh(batches, 1), self._on_mesh(val),
                 faults, self.shared)
             return out
 
     def run_rounds(self, batches, val):
-        """R rounds over ``[R, T, N, ...]`` batches, scanned on-device
-        (engine/gossip) or looped (host). Returns per-round logs — stacked
-        [R, ...] arrays with a ``train`` key on engine/gossip; per-key lists
-        of the R host round logs (see :meth:`round`) on host."""
+        """R rounds over stacked ``[R, T, N, ...]`` batches, scanned
+        on-device in one compiled call. Returns the logs of :meth:`round`
+        stacked over rounds: ``gates`` etc. ``[R, N]``, ``train``
+        ``[R, T, N]``."""
         with tracing.span("round", id=self._dispatched):
-            if self.backend == "host":
-                self._dispatched += len(batches)
-                logs = [self._host_round(rb, val) for rb in batches]
-                return {k: [lg[k] for lg in logs] for k in logs[0]}
             self._dispatched += jax.tree.leaves(batches)[0].shape[0]
             self._state, tm, logs = self._rounds_jit(
                 self._state, self._on_mesh(batches, 2), self._on_mesh(val),
@@ -517,24 +412,12 @@ class SwarmSession:
             return dict(logs, train=tm)
 
     def run_local(self, batches):
-        """Sync-free local training ([S, N, ...] stacked, or [S][N] host)."""
+        """Sync-free local training over stacked ``[S, N, ...]`` batches in
+        one compiled call. Returns the ``[S, N]`` per-step train metrics."""
         with tracing.span("round", id=self._dispatched):
-            if self.backend == "host":
-                for step_batches in batches:
-                    self._learner.local_steps(step_batches)
-                return None
             self._state, tm = self._local_jit(
                 self._state, self._on_mesh(batches, 1), self.shared)
             return tm
-
-    def _host_round(self, batches, val):
-        lr = self._learner
-        for step_batches in batches:
-            lr.local_steps(step_batches)
-        log = lr.sync(val)
-        self._round_ct += 1
-        self._rng = jax.random.fold_in(self._rng, self._round_ct - 1)
-        return log
 
     # -- checkpoint / resume -------------------------------------------------
 

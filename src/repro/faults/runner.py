@@ -31,9 +31,7 @@ from repro.faults.signals import idle_signals, signals_for_round
 
 
 def _supports_in_graph_corrupt(session) -> bool:
-    return (session.backend == "engine"
-            and getattr(session, "_state", None) is not None
-            and session._state.wire is not None)
+    return session.backend == "engine" and session.state.wire is not None
 
 
 def run_plan(session, plan: FaultPlan, batches, val, *,

@@ -1,3 +1,3 @@
-# OPTIONAL layer. Add <name>.py (or .cu) + ops.py + ref.py ONLY
-# for compute hot-spots the paper itself optimizes with a custom
-# kernel. Leave this package empty if the paper has none.
+# Pallas kernels, one <name>.py each, with their plain-jax references in
+# ref.py. Callers import the kernel modules directly;
+# `core.engine.default_interpret` says when to run them in interpret mode.

@@ -36,7 +36,7 @@ the ~6N·BLOCK an unfused numerator/denominator/select chain moves.
 
 ``fused_merge_tree`` maps either entry point leaf-wise over a stacked param
 pytree (2-D ``weights`` selects the all-nodes form, ``imp=`` a matching
-importance pytree); the host-simulated swarm engine commits through it.
+importance pytree); the engine backend's commit runs through it.
 """
 from __future__ import annotations
 

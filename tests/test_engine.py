@@ -1,5 +1,5 @@
-"""The jitted stacked swarm engine: one compiled round must behave exactly
-like the host-simulated `SwarmLearner` loop it replaces."""
+"""The jitted stacked swarm engine: one compiled round must follow the
+float64 numpy twin of the round (`faults.oracle`) on the toy dynamics."""
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -8,7 +8,7 @@ import pytest
 from repro.configs.base import SwarmConfig
 from repro.core import merge_impl as merge_lib
 from repro.core.engine import SwarmEngine
-from repro.core.swarm import NodeState, SwarmLearner
+from repro.faults import oracle
 
 N = 4
 
@@ -39,20 +39,12 @@ def _targets():
     return jnp.asarray([np.full((4,), t, np.float32) for t in range(N)])
 
 
-def test_engine_matches_swarm_learner_toy():
-    """run_rounds == the SwarmLearner loop on the toy quadratic model."""
+def test_engine_matches_oracle_toy():
+    """run_rounds == the numpy oracle's rounds on the toy quadratic model."""
     train_step, eval_fn = _toy_fns()
     cfg = _cfg()
     targets = _targets()
     rounds, t = 3, cfg.sync_every
-
-    nodes = [NodeState(params={"x": jnp.zeros((4,))}, opt_state=None,
-                       data_size=100 * (i + 1)) for i in range(N)]
-    sw = SwarmLearner(cfg, train_step, eval_fn, nodes)
-    for _ in range(rounds):
-        for _ in range(t):
-            sw.local_steps(list(targets))
-        assert sw.maybe_sync([1] * N) is not None
 
     eng = SwarmEngine(cfg, train_step, eval_fn,
                       data_sizes=[100 * (i + 1) for i in range(N)])
@@ -60,7 +52,10 @@ def test_engine_matches_swarm_learner_toy():
     params, _, _, logs = eng.run_rounds({"x": jnp.zeros((N, 4))}, None,
                                         batches, jnp.zeros((N, 1)), None, 0)
     assert np.asarray(logs["gates"]).all()
-    want = np.stack([np.asarray(n.params["x"]) for n in sw.nodes])
+    want = oracle.simulate(
+        np.zeros((N, 4)), np.asarray(targets), np.ones((rounds, N)),
+        merge="fedavg", topology="full", lr=0.1, steps_per_round=t,
+        data_sizes=[100 * (i + 1) for i in range(N)])[-1]
     np.testing.assert_allclose(np.asarray(params["x"]), want,
                                rtol=1e-5, atol=1e-6)
 

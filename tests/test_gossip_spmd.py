@@ -31,7 +31,7 @@ from repro.configs.base import ModelConfig, SwarmConfig, TrainConfig
 from repro.core.gossip import (fedavg_gossip, fisher_gossip, matrix_gossip,
                                ring_gossip)
 from repro.core.merge_impl import fisher_merge
-from repro.core.swarm import gate_decisions, gated_commit
+from repro.core.engine import gate_decisions, gated_commit
 from repro.core.topology import dynamic_matrix, full_matrix, ring_matrix
 from repro.launch.train import (make_swarm_train_step, make_swarm_sync_step,
                                 init_train_state)

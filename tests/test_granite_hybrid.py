@@ -264,7 +264,7 @@ def test_lora_adapters_number_7_2_million_a_site():
     assert {a.dtype for a in adapters.values()} == {jnp.dtype("float32")}
 
 
-@pytest.mark.parametrize("backend, zoo", [("host", False), ("engine", True)])
+@pytest.mark.parametrize("backend, zoo", [("gossip", False), ("engine", True)])
 def test_a_shared_base_needs_one_step_on_the_engine_backend(backend, zoo):
     from repro.core.session import SwarmSession
 

@@ -363,18 +363,6 @@ def test_checkpoint_payload_mode_mismatch_rejected():
         other.load(path)
 
 
-def test_payload_lora_and_zoo_need_a_compiled_backend():
-    nodes = _tiny_zoo()
-    payloads = [nd.payload() for nd in nodes]
-    with pytest.raises(ValueError, match='payload="lora"'):
-        SwarmSession(_cfg(payload="lora"), lambda p, o, b, s: (p, o, {}),
-                     lambda p, v: 1.0, params=payloads, backend="host")
-    fns = [lambda p, o, b, s: (p, o, {})] * N
-    with pytest.raises(ValueError, match="engine-backend"):
-        SwarmSession(_cfg(), fns, lambda p, v: 1.0, params=payloads,
-                     backend="host")
-
-
 def test_zoo_closure_list_length_must_match_n_nodes():
     nodes = _tiny_zoo()
     payloads = [nd.payload() for nd in nodes]

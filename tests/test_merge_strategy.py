@@ -5,8 +5,8 @@ Invariants:
   * the engine's weighted commit (Pallas imp kernel) == merge + gated select,
   * fisher/gradmatch `run_rounds` trace end-to-end (zero host transfers)
     and commit through the fused Pallas kernel,
-  * the jitted engine matches the host-driven SwarmLearner loop for the
-    weighted merges on the toy quadratic model,
+  * the jitted engine matches the float64 numpy oracle (`faults.oracle`)
+    for the weighted merges on the toy quadratic model,
   * the stale-by-one overlap mode stays a convergent gossip scheme.
 """
 import jax
@@ -18,7 +18,8 @@ from repro.configs.base import SwarmConfig
 from repro.core import merge_impl as merge_lib
 from repro.core.engine import SwarmEngine, active_weights, mixing_matrix
 from repro.core.merge_impl import get_strategy
-from repro.core.swarm import NodeState, SwarmLearner
+from repro.core.session import SwarmSession
+from repro.faults import oracle
 
 N = 4
 SEEDS = range(3)
@@ -182,37 +183,18 @@ def test_untrained_node_does_not_dominate_fisher_merge():
     train_step, eval_fn = _toy_fns()
     cfg = _cfg(merge="fisher", sync_every=2)
     # trained nodes start away from their targets so every step accumulates
-    # Δθ² mass; node 3 (params 100.0) is active but never gets a batch
-    nodes = [NodeState(params={"x": jnp.full((4,), 100.0 if i == 3 else -1.0,
-                                             jnp.float32)},
-                       opt_state=None, data_size=100) for i in range(N)]
-    sw = SwarmLearner(cfg, train_step, eval_fn, nodes)
-    targets = list(_targets())
-    for _ in range(2):  # node 3 is active but never gets a batch
-        sw.local_steps(targets[:3] + [None])
-    log = sw.sync([1] * N)
-    assert all(log["gates"])
+    # Δθ² mass; node 3 (params 100.0) is active but its batches equal its
+    # params, so it never moves and never gains mass
+    x0 = jnp.asarray([np.full((4,), 100.0 if i == 3 else -1.0, np.float32)
+                      for i in range(N)])
+    sess = SwarmSession(cfg, train_step, eval_fn, params={"x": x0},
+                        stacked=True, data_sizes=[100] * N)
+    batches = jnp.broadcast_to(_targets().at[3].set(100.0), (2, N, 4))
+    log = sess.round(batches, jnp.zeros((N, 1)))
+    assert np.asarray(log["gates"]).all()
     for i in range(3):
-        merged = np.asarray(sw.nodes[i].params["x"])
+        merged = np.asarray(sess.state.params["x"][i])
         assert np.abs(merged).max() < 5.0, "untrained node took over the merge"
-
-
-def test_explicit_fisher_survives_local_steps():
-    """An explicitly set node.fisher (true squared-grad estimates) is never
-    decayed into the Δθ² proxy — accumulation goes to fisher_stats and the
-    explicit estimate wins at sync."""
-    train_step, eval_fn = _toy_fns()
-    nodes = [NodeState(params={"x": jnp.zeros((4,))}, opt_state=None,
-                       data_size=100) for _ in range(N)]
-    explicit = {"x": jnp.full((4,), 7.0, jnp.float32)}
-    nodes[1].fisher = explicit
-    sw = SwarmLearner(_cfg(merge="fisher"), train_step, eval_fn, nodes)
-    for _ in range(3):
-        sw.local_steps(list(_targets()))
-    assert sw.nodes[1].fisher is explicit            # untouched object
-    assert sw.nodes[1].fisher_stats is not None      # proxy still tracked
-    # node 2 moves toward a nonzero target, so it accumulated real mass
-    assert float(jnp.abs(sw.nodes[2].fisher_stats["x"]).sum()) > 0
 
 
 def test_tiny_accumulated_mass_survives_eps_floor():
@@ -240,21 +222,13 @@ def test_tiny_accumulated_mass_survives_eps_floor():
 
 
 @pytest.mark.parametrize("method", ["fisher", "gradmatch"])
-def test_engine_matches_swarm_learner_weighted(method):
-    """The compiled engine == the host SwarmLearner loop for the weighted
-    merges on the toy quadratic (strategy accumulation on both paths)."""
+def test_engine_matches_oracle_weighted(method):
+    """The compiled engine == the numpy oracle for the weighted merges on
+    the toy quadratic (Δθ² accumulation on both)."""
     train_step, eval_fn = _toy_fns()
     cfg = _cfg(merge=method)
     targets = _targets()
     rounds, t = 3, cfg.sync_every
-
-    nodes = [NodeState(params={"x": jnp.zeros((4,))}, opt_state=None,
-                       data_size=100 * (i + 1)) for i in range(N)]
-    sw = SwarmLearner(cfg, train_step, eval_fn, nodes)
-    for _ in range(rounds):
-        for _ in range(t):
-            sw.local_steps(list(targets))
-        assert sw.maybe_sync([1] * N) is not None
 
     eng = SwarmEngine(cfg, train_step, eval_fn,
                       data_sizes=[100 * (i + 1) for i in range(N)])
@@ -262,7 +236,11 @@ def test_engine_matches_swarm_learner_weighted(method):
     params, _, _, logs = eng.run_rounds({"x": jnp.zeros((N, 4))}, None,
                                         batches, jnp.zeros((N, 1)), None, 0)
     assert np.asarray(logs["gates"]).all()
-    want = np.stack([np.asarray(n.params["x"]) for n in sw.nodes])
+    want = oracle.simulate(
+        np.zeros((N, 4)), np.asarray(targets), np.ones((rounds, N)),
+        merge=method, topology="full", lr=0.1, steps_per_round=t,
+        data_sizes=[100 * (i + 1) for i in range(N)],
+        fisher_decay=cfg.fisher_decay)[-1]
     np.testing.assert_allclose(np.asarray(params["x"]), want,
                                rtol=1e-5, atol=1e-6)
 
@@ -296,30 +274,6 @@ def test_overlap_mode_converges_toy(merge):
     assert np.abs(serial - serial.mean(0)).max() < 1e-5
     assert np.abs(stale - serial).max() < 0.35
     assert np.abs(stale.mean() - serial.mean()) < 0.15
-
-
-def test_mixed_explicit_and_proxy_fishers_do_not_collapse():
-    """Regression: one node supplying explicit squared-grad Fishers (~O(1))
-    among proxy-accumulating peers (~lr² mass) must not swallow the merge —
-    mixed sources are normalized per node before stacking."""
-    train_step, eval_fn = _toy_fns()
-    nodes = [NodeState(params={"x": jnp.full((4,), float(i), jnp.float32)},
-                       opt_state=None, data_size=100) for i in range(N)]
-    nodes[0].fisher = {"x": jnp.ones((4,), jnp.float32)}  # explicit, O(1)
-    sw = SwarmLearner(_cfg(merge="fisher"), train_step, eval_fn, nodes)
-    # batch targets sit off every node's params so each step moves the
-    # params and deposits (tiny, lr²-scaled) Δθ² mass
-    offset = [jnp.full((4,), i + 0.5, jnp.float32) for i in range(N)]
-    for _ in range(2):
-        sw.local_steps(offset)
-    x0 = np.asarray(sw.nodes[0].params["x"]).copy()  # pre-sync local params
-    log = sw.sync([1] * N)
-    assert all(log["gates"])
-    merged = np.asarray(sw.nodes[1].params["x"])
-    # a genuine blend: clearly away from node 0's params (pre-fix the merge
-    # collapsed onto them) and inside the swarm's param range
-    assert np.abs(merged - x0).min() > 0.3
-    assert merged.max() <= 3.2 and merged.min() >= 0.0
 
 
 def test_overlap_histo_smoke_converges():

@@ -1,7 +1,10 @@
 """SwarmSession: the backend-agnostic API over one SwarmState pytree.
 
 Pins the redesign's acceptance criteria:
-  * session drivers == the legacy SwarmEngine/SwarmLearner paths,
+  * session drivers == the SwarmEngine tuple API and the numpy oracle,
+  * the backend is "engine" or "gossip", the same two names on the session
+    and the engine; ``round``/``run_rounds``/``run_local`` share one
+    stacked-array contract,
   * join→leave→rejoin mid-run reuses the compiled round (ZERO retraces,
     asserted via a trace counter in the train step's python body),
   * ring/dynamic fisher & gradmatch merges match the numpy host oracle
@@ -28,6 +31,7 @@ from repro.core import merge_impl as merge_lib
 from repro.core import topology as topo
 from repro.core.engine import SwarmEngine, active_weights
 from repro.core.session import SwarmSession, SwarmState
+from repro.faults import oracle
 
 N = 4
 
@@ -310,54 +314,80 @@ def test_checkpoint_legacy_keys_still_load(tmp_path):
 
 
 # ---------------------------------------------------------------------------
-# host backend
+# the backends and the drivers' one contract
 # ---------------------------------------------------------------------------
 
-def test_host_backend_matches_engine_backend():
-    """The same toy schedule through backend="host" (SwarmLearner loop)
-    and the compiled engine backend lands on the same params — including
-    after a leave(): on BOTH backends a departed node that still receives
+@pytest.mark.parametrize("backend", ["host", "spmd"])
+def test_backend_names_are_engine_and_gossip(backend):
+    """The session and the engine take the same two backend names and
+    refuse any other, naming the two."""
+    with pytest.raises(ValueError, match='"engine" or "gossip"'):
+        _session(_cfg(), backend=backend)
+    with pytest.raises(ValueError, match='"engine" or "gossip"'):
+        SwarmEngine(_cfg(), *_toy_fns(), backend=backend)
+
+
+def test_session_passes_its_backend_to_the_engine():
+    sess = _session(_cfg())
+    assert sess.backend == sess.engine.backend == "engine"
+    assert SwarmEngine(_cfg(), *_toy_fns()).backend == "engine"
+
+
+def test_session_matches_oracle_through_leave_and_join():
+    """The toy schedule through the session lands on the numpy oracle's
+    params — including after a leave(): a departed node that still receives
     batches keeps training locally and is only excluded from merges."""
     cfg = _cfg(topology="dynamic")
-    targets = list(_targets())
-    host = _session(cfg, backend="host")
-    eng = _session(cfg)
-    ebatches = jnp.broadcast_to(_targets(), (2, N, 4))
+    sess = _session(cfg)
+    batches = jnp.broadcast_to(_targets(), (2, N, 4))
     val = jnp.zeros((N, 1))
-    for sess in (host, eng):
-        sess.round([targets, targets] if sess is host else ebatches,
-                   [1] * N if sess is host else val)
-        sess.leave(3)
-        sess.round([targets, targets] if sess is host else ebatches,
-                   [1] * N if sess is host else val)
-        sess.join(3)
-        sess.round([targets, targets] if sess is host else ebatches,
-                   [1] * N if sess is host else val)
-    np.testing.assert_allclose(
-        np.asarray(host.state.params["x"]),
-        np.asarray(eng.state.params["x"]), rtol=1e-5, atol=1e-6)
-    assert int(host.state.round) == int(eng.state.round) == 3
+    sess.round(batches, val)
+    sess.leave(3)
+    sess.round(batches, val)
+    sess.join(3)
+    sess.round(batches, val)
+    want = oracle.simulate(
+        np.zeros((N, 4)), np.asarray(_targets()),
+        np.asarray([[1, 1, 1, 1], [1, 1, 1, 0], [1, 1, 1, 1]]),
+        merge="fedavg", topology="dynamic", lr=0.1, steps_per_round=2,
+        data_sizes=[100 * (i + 1) for i in range(N)])[-1]
+    np.testing.assert_allclose(np.asarray(sess.state.params["x"]), want,
+                               rtol=1e-5, atol=1e-6)
+    assert int(sess.state.round) == 3
 
 
-def test_host_backend_checkpoint_roundtrip(tmp_path):
-    cfg = _cfg(merge="fisher")
-    sess = _session(cfg, backend="host")
-    targets = list(_targets())
-    sess.round([targets, targets], [1] * N)
-    sess.leave(1)
-    path = str(tmp_path / "host.msgpack")
-    sess.save(path)
-    restored = SwarmSession.restore(
-        path, cfg, *_toy_fns(), backend="host",
-        params={"x": jnp.zeros((4,))},
-        data_sizes=[100 * (i + 1) for i in range(N)])
-    np.testing.assert_array_equal(restored.active, [True, False, True, True])
-    np.testing.assert_array_equal(
-        np.asarray(restored.state.params["x"]),
-        np.asarray(sess.state.params["x"]))
-    np.testing.assert_array_equal(
-        np.asarray(restored.state.stats["x"]),
-        np.asarray(sess.state.stats["x"]))
+def test_run_rounds_logs_are_round_logs_stacked():
+    """run_rounds returns what R round() calls return, stacked over R."""
+    cfg = _cfg(merge="fisher", quorum=2)
+    batches = jnp.broadcast_to(_targets(), (3, 2, N, 4))
+    val = jnp.zeros((N, 1))
+    scanned = _session(cfg)
+    logs = scanned.run_rounds(batches, val)
+    looped = _session(cfg)
+    each = [looped.round(batches[r], val) for r in range(3)]
+    assert set(logs) == set(each[0])
+    for key in logs:
+        want = jax.tree.map(lambda *xs: np.stack(xs), *each)[key]
+        jax.tree.map(lambda a, b: np.testing.assert_allclose(
+            np.asarray(a), b, rtol=1e-6), logs[key], want)
+    assert np.asarray(logs["gates"]).shape == (3, N)
+    assert np.asarray(logs["train"]["loss"]).shape == (3, 2, N)
+    np.testing.assert_allclose(np.asarray(scanned.state.params["x"]),
+                               np.asarray(looped.state.params["x"]),
+                               rtol=1e-6)
+
+
+def test_run_local_trains_without_sync():
+    """run_local: S local steps on stacked [S, N, ...] batches, [S, N]
+    metrics back, the step counter advanced and no merge."""
+    sess = _session(_cfg())
+    tm = sess.run_local(jnp.broadcast_to(_targets(), (3, N, 4)))
+    assert np.asarray(tm["loss"]).shape == (3, N)
+    # pure local descent toward each node's target: x = t·(1 − 0.9³)
+    want = np.asarray(_targets()) * (1 - 0.9 ** 3)
+    np.testing.assert_allclose(np.asarray(sess.state.params["x"]), want,
+                               rtol=1e-6)
+    assert int(sess.state.step) == 3 and int(sess.state.round) == 0
 
 
 # ---------------------------------------------------------------------------
@@ -428,9 +458,10 @@ def test_histo_loop_with_f1_gate_runs():
 # true-Fisher accumulation hook
 # ---------------------------------------------------------------------------
 
-def test_four_tuple_train_step_accumulates_exact_grad_squares():
+@pytest.mark.parametrize("merge", ["fisher", "gradmatch"])
+def test_four_tuple_train_step_accumulates_exact_grad_squares(merge):
     """A train step returning (params, opt, metrics, grads) feeds F ← γF + g²
-    (exact squared gradients) instead of the Δθ² proxy — engine path."""
+    (exact squared gradients) instead of the Δθ² proxy."""
     decay = 0.5
 
     def grad_step(p, o, b, s):
@@ -438,7 +469,7 @@ def test_four_tuple_train_step_accumulates_exact_grad_squares():
         return {"x": p["x"] - 0.1 * g}, o, {"loss": jnp.sum(g * g)}, {"x": g}
 
     _, eval_fn = _toy_fns()
-    cfg = _cfg(merge="fisher", fisher_decay=decay)
+    cfg = _cfg(merge=merge, fisher_decay=decay)
     eng = SwarmEngine(cfg, grad_step, eval_fn, data_sizes=[1] * N)
     batches = jnp.broadcast_to(_targets(), (2, N, 4))
     p0 = {"x": jnp.zeros((N, 4))}
@@ -448,26 +479,3 @@ def test_four_tuple_train_step_accumulates_exact_grad_squares():
     # g0 = -t; θ1 = 0.1t; g1 = -0.9t  ->  F = γ·g0² + g1²
     want = decay * t ** 2 + (0.9 * t) ** 2
     np.testing.assert_allclose(np.asarray(stats["x"]), want, rtol=1e-5)
-
-
-def test_four_tuple_train_step_host_path():
-    """Same hook through the SwarmLearner (host) loop."""
-    from repro.core.swarm import NodeState, SwarmLearner
-    decay = 0.5
-
-    def grad_step(p, o, b, s):
-        g = p["x"] - b
-        return {"x": p["x"] - 0.1 * g}, o, {"loss": float(jnp.sum(g * g))}, \
-            {"x": g}
-
-    nodes = [NodeState(params={"x": jnp.zeros((4,))}, opt_state=None,
-                       data_size=100) for _ in range(N)]
-    sw = SwarmLearner(_cfg(merge="fisher", fisher_decay=decay),
-                      grad_step, lambda p, v: 1.0, nodes)
-    targets = list(_targets())
-    for _ in range(2):
-        sw.local_steps(targets)
-    t = np.full(4, 3.0, np.float32)  # node 3's target
-    want = decay * t ** 2 + (0.9 * t) ** 2
-    np.testing.assert_allclose(np.asarray(nodes[3].fisher_stats["x"]), want,
-                               rtol=1e-5)

@@ -3,6 +3,8 @@
 `hypothesis` is not installable in this offline container; the same invariants
 are asserted over seed-swept random instances instead.
 """
+import dataclasses
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -10,10 +12,11 @@ import pytest
 
 from repro.configs.base import SwarmConfig
 from repro.core import topology as topo
+from repro.core.engine import (gate_decisions, gated_commit, mixing_matrix,
+                               propose_merge)
 from repro.core.merge_impl import (fisher_merge, gradmatch_merge, mix,
                                    stack_params, unstack_params)
-from repro.core.swarm import (SwarmLearner, NodeState, gate_decisions,
-                              gated_commit, mixing_matrix, propose_merge)
+from repro.core.session import SwarmSession
 
 SEEDS = range(8)
 
@@ -190,48 +193,53 @@ def test_propose_merge_lora_only_leaves_base_untouched():
 
 
 # ---------------------------------------------------------------------------
-# end-to-end SwarmLearner behaviour (toy quadratic "model")
+# end-to-end session behaviour (toy quadratic "model")
 # ---------------------------------------------------------------------------
 
-def _toy_learner(sync_every=2, merge="fedavg", threshold=0.0):
+TARGETS = jnp.asarray([np.full((4,), t, np.float32) for t in range(4)])
+
+
+def _pull_step(params, opt_state, batch, step):
+    """Each node descends toward its batch (its target); a batch equal to
+    the node's params leaves it where it is."""
+    g = params["x"] - batch
+    return {"x": params["x"] - 0.1 * g}, opt_state, {"loss": jnp.sum(g * g)}
+
+
+def _accept(params, val):
+    return 1.0 - 0.0 * jnp.sum(params["x"])  # always accept, in-graph
+
+
+def _toy_session(sync_every=2, merge="fedavg", threshold=0.0, *,
+                 train_step=_pull_step, eval_fn=_accept, params=None,
+                 stacked=False, data_sizes=(100, 200, 300, 400)):
     """Nodes descend toward different targets; swarm pulls them together."""
-    targets = [jnp.full((4,), t, jnp.float32) for t in (0.0, 1.0, 2.0, 3.0)]
-
-    def train_step(params, opt_state, batch, step):
-        i = batch
-        g = params["x"] - targets[i]
-        return {"x": params["x"] - 0.1 * g}, opt_state, {"loss": float(jnp.sum(g**2))}
-
-    def eval_fn(params, val):
-        return 1.0  # always accept (threshold tested separately)
-
-    nodes = [NodeState(params={"x": jnp.zeros((4,))}, opt_state=None,
-                       data_size=100 * (i + 1)) for i in range(4)]
     cfg = SwarmConfig(n_nodes=4, sync_every=sync_every, merge=merge,
                       topology="full", lora_only=False, val_threshold=threshold)
-    return SwarmLearner(cfg, train_step, eval_fn, nodes)
+    return SwarmSession(cfg, train_step, eval_fn,
+                        params=params or {"x": jnp.zeros((4,))},
+                        stacked=stacked, data_sizes=data_sizes)
 
 
-def test_swarm_learner_syncs_to_weighted_mean():
-    sw = _toy_learner()
-    for _ in range(2):
-        sw.local_steps([0, 1, 2, 3])
-    log = sw.sync([1, 1, 1, 1])
-    assert all(log["gates"])
-    xs = [np.asarray(n.params["x"]) for n in sw.nodes]
+def test_session_syncs_to_weighted_mean():
+    sess = _toy_session()
+    log = sess.round(jnp.broadcast_to(TARGETS, (2, 4, 4)), jnp.zeros((4, 1)))
+    assert np.asarray(log["gates"]).all()
+    xs = np.asarray(sess.state.params["x"])
     for x in xs[1:]:
         np.testing.assert_allclose(x, xs[0], rtol=1e-5, atol=1e-6)
 
 
-def test_swarm_learner_dynamic_membership():
-    sw = _toy_learner()
-    sw.set_active(2, False)
-    for _ in range(2):
-        sw.local_steps([0, 1, None, 3])
-    x2_before = np.asarray(sw.nodes[2].params["x"]).copy()
-    log = sw.sync([1, 1, None, 1])
-    assert log["gates"][2] is False or log["gates"][2] == 0
-    np.testing.assert_allclose(np.asarray(sw.nodes[2].params["x"]), x2_before)
+def test_session_dynamic_membership():
+    sess = _toy_session()
+    sess.leave(2)
+    x2_before = np.asarray(sess.state.params["x"][2]).copy()
+    # node 2's padding equals its params, so its steps leave it in place
+    batches = jnp.broadcast_to(TARGETS.at[2].set(x2_before), (2, 4, 4))
+    log = sess.round(batches, jnp.zeros((4, 1)))
+    assert not np.asarray(log["gates"])[2]
+    np.testing.assert_allclose(np.asarray(sess.state.params["x"][2]),
+                               x2_before)
 
 
 @pytest.mark.parametrize("merge", ["fisher", "gradmatch"])
@@ -239,46 +247,38 @@ def test_inactive_node_excluded_from_weighted_merges(merge):
     """Regression: a departed node's (huge) Fisher mass and dataset weight
     must not leak into fisher/gradmatch merges — zero + renormalize over the
     active membership."""
-    nodes = []
-    for i in range(4):
-        params = {"x": jnp.full((8,), float(i), jnp.float32)}
-        nodes.append(NodeState(
-            params=params, opt_state=None, data_size=100,
-            fisher=jax.tree.map(
-                lambda t: jnp.full_like(t, 1e6 if i == 2 else 1.0), params)))
-    cfg = SwarmConfig(n_nodes=4, sync_every=1, merge=merge, topology="full",
-                      lora_only=False, val_threshold=0.0)
-    sw = SwarmLearner(cfg, lambda p, o, b, s: (p, o, {}),
-                      lambda p, v: 1.0, nodes)
-    sw.set_active(2, False)
-    sw.step = 1
-    log = sw.sync([1, 1, None, 1])
-    assert not log["gates"][2]
+    sess = _toy_session(sync_every=1, merge=merge,
+                        train_step=lambda p, o, b, s: (p, o, {}),
+                        data_sizes=[100] * 4,
+                        params=[{"x": jnp.full((8,), float(i), jnp.float32)}
+                                for i in range(4)])
+    mass = jnp.asarray([1.0, 1.0, 1e6, 1.0])[:, None] * jnp.ones((4, 8))
+    sess.load_state(dataclasses.replace(sess.state, stats={"x": mass}))
+    sess.leave(2)
+    log = sess.round(jnp.zeros((1, 4, 1)), jnp.zeros((4, 1)))
+    assert not np.asarray(log["gates"])[2]
     # active nodes merge to mean(0, 1, 3); node 2 (params=2, fisher=1e6)
     # would drag the result toward 2.0 if it leaked in
+    x = np.asarray(sess.state.params["x"])
     for i in (0, 1, 3):
-        np.testing.assert_allclose(np.asarray(sw.nodes[i].params["x"]),
-                                   np.full(8, 4.0 / 3), rtol=1e-4)
-    np.testing.assert_allclose(np.asarray(sw.nodes[2].params["x"]),
-                               np.full(8, 2.0))
+        np.testing.assert_allclose(x[i], np.full(8, 4.0 / 3), rtol=1e-4)
+    np.testing.assert_allclose(x[2], np.full(8, 2.0))
 
 
-def test_swarm_learner_gate_rejects_bad_merges():
-    sw = _toy_learner()
-    # eval_fn returning lower metric for merged candidate -> reject
-    calls = {"n": 0}
+def test_session_gate_rejects_bad_merges():
+    # each node starts at its own target and scores by its distance to it
+    # (val), so the fedavg candidate (5.0, at no node's target) scores lower
+    # than every local -> reject
+    targets = jnp.asarray([np.full((4,), t * t, np.float32)
+                           for t in range(4)])
 
     def eval_fn(params, val):
-        calls["n"] += 1
-        return 0.1 if calls["n"] % 2 == 0 else 1.0  # merged evaluated second
+        return 1.0 / (1.0 + jnp.sum((params["x"] - val) ** 2))
 
-    sw.eval_fn = eval_fn
-    sw.cfg = SwarmConfig(n_nodes=4, sync_every=2, merge="fedavg",
-                         topology="full", lora_only=False, val_threshold=0.8)
-    for _ in range(2):
-        sw.local_steps([0, 1, 2, 3])
-    before = [np.asarray(n.params["x"]).copy() for n in sw.nodes]
-    log = sw.sync([1, 1, 1, 1])
-    assert not any(log["gates"])
-    for b, n in zip(before, sw.nodes):
-        np.testing.assert_allclose(np.asarray(n.params["x"]), b)
+    sess = _toy_session(threshold=0.8, eval_fn=eval_fn,
+                        params={"x": jnp.array(targets)}, stacked=True)
+    before = np.asarray(targets).copy()   # the locals after the steps
+    log = sess.round(jnp.broadcast_to(targets, (2, 4, 4)), targets)
+    assert not np.asarray(log["gates"]).any()
+    for b, x in zip(before, np.asarray(sess.state.params["x"])):
+        np.testing.assert_allclose(x, b)
