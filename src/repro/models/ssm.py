@@ -3,8 +3,10 @@
 Follows the SSD formulation of arXiv:2405.21060: within a chunk the recurrence
 is materialized as a decay-masked quadratic form (MXU-friendly); across chunks
 a short scan carries the [H, P, N] state. The Pallas ``ssd_scan`` kernel in
-``repro.kernels`` implements the same chunked schedule with explicit VMEM
-tiling; this module is the lowering/oracle path.
+``repro.kernels`` runs the same chunk schedule with explicit VMEM tiling, but
+takes B and C per head ([B, S, H, N]), where ``ssd_chunked`` takes them per
+group: a caller with fewer groups than heads repeats them for the kernel.
+This module is the path the models lower.
 
 Decode keeps an O(1) recurrent state — this is what makes ``long_500k``
 natively sub-quadratic for the ssm/hybrid families.
@@ -96,41 +98,47 @@ def ssd_chunked(x, dt, a_log, bmat, cmat, chunk: int):
     bmat [B,S,G,N]   input->state projection
     cmat [B,S,G,N]   state->output projection
     Returns y [B,S,H,P], final_state [B,H,P,N].
+
+    B and C are never copied per head: heads are split as [G, R] (R = H/G
+    heads share a group) wherever they meet B or C, so ``C·Bᵀ`` is computed
+    once per group and broadcast over its heads.
     """
     b, s, h, pdim = x.shape
     g, n = bmat.shape[2], bmat.shape[3]
     rep = h // g
     nc = s // chunk
     dtype = x.dtype
+    f32 = jnp.float32
 
     # per-step log decay
-    dA = dt * (-jnp.exp(a_log.astype(jnp.float32)))       # [B,S,H] (<0)
+    dA = dt * (-jnp.exp(a_log.astype(f32)))                # [B,S,H] (<0)
     xdt = x * dt[..., None].astype(dtype)                  # weight input by dt
 
     def ch(t):  # reshape into chunks
         return t.reshape(b, nc, chunk, *t.shape[2:])
 
-    xc, dAc = ch(xdt), ch(dA)
-    bc = jnp.repeat(ch(bmat), rep, axis=3)                 # [B,nc,L,H,N]
-    cc = jnp.repeat(ch(cmat), rep, axis=3)
+    xc = ch(xdt).astype(f32).reshape(b, nc, chunk, g, rep, pdim)
+    bc, cc = ch(bmat).astype(f32), ch(cmat).astype(f32)   # [B,nc,L,G,N]
 
-    cum = jnp.cumsum(dAc, axis=2)                          # [B,nc,L,H]
+    cum = jnp.cumsum(ch(dA), axis=2)                       # [B,nc,L,H]
     # intra-chunk: decay-masked quadratic attention
-    # L_mat[i,j] = exp(cum_i - cum_j) for i >= j else 0
-    diff = cum[:, :, :, None, :] - cum[:, :, None, :, :]   # [B,nc,L,L,H]
+    # L_mat[i,j] = exp(cum_i - cum_j) for i >= j else 0, laid out per head
+    # as [B,nc,G,R,L,L] so that the group's scores broadcast over R
+    cum_t = jnp.moveaxis(cum, 2, 3).reshape(b, nc, g, rep, chunk)
+    diff = cum_t[..., :, None] - cum_t[..., None, :]       # [B,nc,G,R,L,L]
     ii = jnp.arange(chunk)
-    causal = (ii[:, None] >= ii[None, :])[None, None, :, :, None]
+    causal = ii[:, None] >= ii[None, :]
     # mask BEFORE exp: exp of masked (positive) entries overflows and poisons
     # the backward pass with inf*0 = NaN
     lmat = jnp.exp(jnp.where(causal, diff, -1e30))
-    scores = jnp.einsum("bclhn,bcmhn->bclmh", cc.astype(jnp.float32), bc.astype(jnp.float32))
-    y_diag = jnp.einsum("bclmh,bclmh,bcmhp->bclhp", scores, lmat,
-                        xc.astype(jnp.float32))
+    scores = jnp.einsum("bclgn,bcmgn->bcglm", cc, bc)      # [B,nc,G,L,L]
+    y_diag = jnp.einsum("bcgrlm,bcmgrp->bclgrp",
+                        scores[:, :, :, None] * lmat, xc)
 
     # chunk-final states: sum_j exp(cum_last - cum_j) * B_j ⊗ x_j
     decay_to_end = jnp.exp(cum[:, :, -1:, :] - cum)        # [B,nc,L,H]
-    states = jnp.einsum("bclhn,bclh,bclhp->bchpn",
-                        bc.astype(jnp.float32), decay_to_end, xc.astype(jnp.float32))
+    states = jnp.einsum("bclgn,bclgr,bclgrp->bcgrpn", bc,
+                        decay_to_end.reshape(b, nc, chunk, g, rep), xc)
 
     # inter-chunk recurrence
     chunk_decay = jnp.exp(cum[:, :, -1, :])                # [B,nc,H]
@@ -140,16 +148,19 @@ def ssd_chunked(x, dt, a_log, bmat, cmat, chunk: int):
         new = carry * dec[:, :, None, None] + st_in
         return new, carry                                   # emit state BEFORE chunk
 
-    init = jnp.zeros((b, h, pdim, n), jnp.float32)
+    init = jnp.zeros((b, h, pdim, n), f32)
     final, prev_states = jax.lax.scan(
         step, init,
-        (jnp.moveaxis(states, 1, 0), jnp.moveaxis(chunk_decay, 1, 0)),
+        (jnp.moveaxis(states.reshape(b, nc, h, pdim, n), 1, 0),
+         jnp.moveaxis(chunk_decay, 1, 0)),
     )
     prev_states = jnp.moveaxis(prev_states, 0, 1)           # [B,nc,H,P,N]
 
-    # inter-chunk contribution: C_t · (decay to t) · S_prev
-    y_off = jnp.einsum("bclhn,bclh,bchpn->bclhp",
-                       cc.astype(jnp.float32), jnp.exp(cum), prev_states)
+    # inter-chunk contribution: C_t · (decay to t) · S_prev; C meets the
+    # state before the decay, which would otherwise spread it over R heads
+    y_off = jnp.einsum("bclgn,bcgrpn->bclgrp", cc,
+                       prev_states.reshape(b, nc, g, rep, pdim, n))
+    y_off = y_off * jnp.exp(cum).reshape(b, nc, chunk, g, rep, 1)
 
     y = (y_diag + y_off).reshape(b, s, h, pdim).astype(dtype)
     return y, final

@@ -16,6 +16,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax.extend.core import ClosedJaxpr, Jaxpr
 
 from repro.configs import get_config
 from repro.configs.base import SwarmConfig, TrainConfig
@@ -64,18 +65,87 @@ def _with_adapters(seed=0):
     return base, adapters
 
 
-def test_chunked_ssd_matches_the_recurrence():
-    h, p, n = 8, 16, 16
+SSD_HEADS = 8
+GROUPS = pytest.mark.parametrize("g", [1, 2], ids=["one_group",
+                                                   "two_groups"])
+
+
+def _ssd_inputs(g, s=S, h=SSD_HEADS, p=16, n=16, batch=1):
+    """x, dt (after softplus), a_log, B and C of ``g`` groups."""
     ks = jax.random.split(jax.random.key(0), 4)
-    x = jax.random.normal(ks[0], (1, S, h, p))
-    dt = jax.nn.softplus(jax.random.normal(ks[1], (1, S, h)))
-    b = jax.random.normal(ks[2], (1, S, 1, n))
-    c = jax.random.normal(ks[3], (1, S, 1, n))
-    a_log = jnp.log(jnp.linspace(1.0, 16.0, h))
+    return (jax.random.normal(ks[0], (batch, s, h, p)),
+            jax.nn.softplus(jax.random.normal(ks[1], (batch, s, h))),
+            jnp.log(jnp.linspace(1.0, 16.0, h)),
+            jax.random.normal(ks[2], (batch, s, g, n)),
+            jax.random.normal(ks[3], (batch, s, g, n)))
+
+
+@GROUPS
+def test_chunked_ssd_matches_the_recurrence(g):
+    x, dt, a_log, b, c = _ssd_inputs(g)
     y, _ = ssd_chunked(x, dt, a_log, b, c, chunk=16)
     want = ref.ssd_sequential(x[0], dt[0], a_log, b[0], c[0])
     np.testing.assert_allclose(np.asarray(y[0]), np.asarray(want),
                                rtol=RTOL, atol=ATOL)
+
+
+@GROUPS
+def test_chunked_ssd_gradients_match_the_recurrence(g):
+    """The gradients the LM's training step takes through the scan, of
+    x, dt, B and C, against `jax.grad` through the step-by-step
+    recurrence."""
+    x, dt, a_log, b, c = _ssd_inputs(g)
+    w = jax.random.normal(jax.random.key(1), x.shape[1:])
+
+    def chunked(x, dt, b, c):
+        return jnp.sum(ssd_chunked(x, dt, a_log, b, c, chunk=16)[0][0] * w)
+
+    def sequential(x, dt, b, c):
+        return jnp.sum(ref.ssd_sequential(x[0], dt[0], a_log, b[0], c[0])
+                       * w)
+
+    args = (x, dt, b, c)
+    got = jax.jit(jax.grad(chunked, argnums=(0, 1, 2, 3)))(*args)
+    want = jax.jit(jax.grad(sequential, argnums=(0, 1, 2, 3)))(*args)
+    for name, gv, wv in zip("x dt B C".split(), got, want):
+        scale = float(jnp.max(jnp.abs(wv)))
+        assert scale > 0, name
+        np.testing.assert_allclose(np.asarray(gv), np.asarray(wv),
+                                   rtol=RTOL, atol=ATOL * scale,
+                                   err_msg=name)
+
+
+def _avals(jaxpr):
+    """Every value a jaxpr and the jaxprs inside it (scan bodies,
+    checkpoints, nested jits) compute."""
+    for eqn in jaxpr.eqns:
+        yield from (v.aval for v in eqn.outvars)
+        for param in eqn.params.values():
+            for sub in param if isinstance(param, (list, tuple)) else [param]:
+                if isinstance(sub, ClosedJaxpr):
+                    sub = sub.jaxpr
+                if isinstance(sub, Jaxpr):
+                    yield from _avals(sub)
+
+
+def test_chunked_ssd_holds_no_per_head_copy_of_b_or_c():
+    """Neither the scan nor its gradient lays B, C or their gradients out
+    once per head: with 8 heads in 2 groups, no value of the traced
+    value-and-gradient has the head-repeated shape [b, chunks, chunk, h,
+    n], nor that shape with the heads split as [groups, h / groups]."""
+    batch, g, h, n, chunk = 2, 2, SSD_HEADS, 12, 16   # n unlike p (16)
+    x, dt, a_log, b, c = _ssd_inputs(g, s=4 * chunk, h=h, n=n, batch=batch)
+
+    def loss(x, dt, b, c):
+        return jnp.sum(ssd_chunked(x, dt, a_log, b, c, chunk)[0])
+
+    jaxpr = jax.make_jaxpr(jax.value_and_grad(loss, argnums=(0, 1, 2, 3)))(
+        x, dt, b, c)
+    repeated = {(batch, 4, chunk, h, n), (batch, 4, chunk, g, h // g, n)}
+    shapes = {tuple(a.shape) for a in _avals(jaxpr.jaxpr)
+              if hasattr(a, "shape")}
+    assert (batch, 4, chunk, g, n) in shapes      # B and C per group
+    assert not shapes & repeated, shapes & repeated
 
 
 @pytest.mark.parametrize("s", [S, 75], ids=["whole_chunks", "ragged"])
